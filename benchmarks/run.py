@@ -22,6 +22,9 @@ def main() -> None:
                     help="directory for BENCH_<name>.json files")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         cleanup_bench,
         common,

@@ -110,6 +110,12 @@ class Backend(abc.ABC):
         """
         return 1
 
+    @property
+    def input_sharding(self):
+        """Where the facade places a host-side bulk-build input before the
+        build program reads it; None leaves it on jax's default device."""
+        return None
+
     # -- construction -------------------------------------------------------
 
     @classmethod

@@ -40,6 +40,7 @@ import dataclasses
 import math
 
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.api.backend import (
     Backend,
@@ -248,6 +249,13 @@ class ShardedLSMBackend(Backend):
     @property
     def has_write_buffer(self) -> bool:
         return True
+
+    @property
+    def input_sharding(self):
+        # Every shard reads the whole key set (dist_bulk_build); copying it
+        # from the host to each device directly keeps device 0 from holding
+        # a staged copy on top of its replica.
+        return NamedSharding(self.mesh, PartitionSpec())
 
     def init(self):
         return dist.dist_lsm_init(self.cfg, self.mesh)

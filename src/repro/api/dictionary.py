@@ -37,8 +37,9 @@ Design:
 
 from __future__ import annotations
 
+import concurrent.futures
 import functools
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,15 +54,22 @@ from repro.api.backend import (
 from repro.api.plan import QueryPlan
 from repro.core import semantics as sem
 from repro.core.lsm import compact_real
+from repro.kernels import ops
 
-# (backend, op, statics) -> jitted executable. jax.jit keeps the per-shape
-# specialization under each entry, so this stays small: one entry per
-# (config, op) the process touches.
+# (backend, op, statics, kernel backend) -> jitted executable. jax.jit keeps
+# the per-shape specialization under each entry, so this stays small: one
+# entry per (config, op) the process touches. The kernel backend is part of
+# the key because it is read while tracing: after `ops.set_backend(...)` an
+# op must trace anew, not reuse a program built on the other kernels.
 _EXEC_CACHE: Dict[tuple, object] = {}
+
+# `precompile` threads: XLA compiles each program on one core, so the
+# programs of a run compile side by side.
+_COMPILE_THREADS = 16
 
 
 def _cached_exec(backend: Backend, op: str, fn, *, donate_state: bool = False, statics=()):
-    key = (backend, op, statics)
+    key = (backend, op, statics, ops.get_backend())
     f = _EXEC_CACHE.get(key)
     if f is None:
         f = jax.jit(
@@ -206,8 +214,13 @@ def _check_key_domain(name: str, keys, valid=None) -> None:
         )
 
 
-def _as_keys(name: str, x):
-    arr = jnp.asarray(x, jnp.int32)
+def _as_keys(name: str, x, sharding=None):
+    """int32 1-D device array; host input goes straight to `sharding` when
+    one is given."""
+    if sharding is not None and _is_concrete(x) and not isinstance(x, jax.Array):
+        arr = jax.device_put(np.asarray(x, np.int32), sharding)
+    else:
+        arr = jnp.asarray(x, jnp.int32)
     if arr.ndim == 0:
         arr = arr[None]
     if arr.ndim != 1:
@@ -336,6 +349,90 @@ class Dictionary:
         return Dictionary(self._backend, new_state, self._validate,
                           self._flush_threshold, self._maintenance_budget)
 
+    # -- programs: the one place each op's statics and donation are decided --
+
+    def _update_exec(self):
+        return _cached_exec(
+            self._backend, "update", _exec_update, donate_state=True,
+            statics=(self._flush_threshold, self._maintenance_budget),
+        )
+
+    def _bulk_build_exec(self):
+        return _cached_exec(self._backend, "bulk_build", _exec_bulk_build)
+
+    def _flush_exec(self):
+        return _cached_exec(
+            self._backend, "flush", _exec_flush, donate_state=True,
+            statics=(self._maintenance_budget,),
+        )
+
+    def _maintain_exec(self, budget: Optional[int]):
+        if budget is None:
+            budget = self._maintenance_budget
+        else:
+            budget = int(budget)
+            if budget < 1:
+                raise ValueError(f"maintain budget must be >= 1, got {budget}")
+        return _cached_exec(
+            self._backend, "maintain", _exec_maintain, donate_state=True,
+            statics=(budget,),
+        )
+
+    def _cleanup_exec(self):
+        return _cached_exec(self._backend, "cleanup", _exec_cleanup, donate_state=True)
+
+    def _lookup_exec(self):
+        return _cached_exec(self._backend, "lookup", _exec_lookup)
+
+    def _window_exec(self, op: str, plan: Optional[QueryPlan]):
+        fn = {"count": _exec_count, "range": _exec_range}[op]
+        return _cached_exec(self._backend, op, fn, statics=(self._resolved_plan(plan),))
+
+    def precompile(self, *, bulk: Optional[int] = None, lookups: Sequence[int] = (),
+                   updates: Sequence[int] = (), windows: Sequence[int] = (),
+                   plans: Sequence[Optional[QueryPlan]] = (None,),
+                   maintain: Sequence[Optional[int]] = (), flush: bool = False,
+                   cleanup: bool = False) -> dict:
+        """Compile, concurrently, the programs that later calls on this
+        handle's configuration will run, so that those calls do not compile.
+
+        `bulk`: the key count of a later `bulk_build`. `lookups`, `updates`,
+        `windows`: lane widths of later `lookup`, masked `update(...,
+        valid=...)` and `count`/`range` calls; each window width compiles
+        count and range under every plan in `plans`. `maintain`: budgets of
+        later `maintain` calls. `flush`, `cleanup`: those programs too.
+
+        Each is the very program the method runs (same statics, donation,
+        kernel backend, argument shapes and placement); jax keys compiled
+        programs on exactly that, so the later call finds it. Returns
+        {(op, *shape or static): compiled program}.
+        """
+        st, sh = self._state, self._backend.input_sharding
+        lanes = lambda w, dt=jnp.int32, where=None: jax.ShapeDtypeStruct((w,), dt, sharding=where)  # noqa: E731
+        progs = {}
+        if bulk is not None:
+            progs[("bulk_build", bulk)] = (self._bulk_build_exec(),
+                                           (lanes(bulk, where=sh), lanes(bulk, where=sh)))
+        for w in lookups:
+            progs[("lookup", w)] = (self._lookup_exec(), (st, lanes(w)))
+        for w in updates:
+            progs[("update", w)] = (self._update_exec(),
+                                    (st, lanes(w), lanes(w), lanes(w, bool), lanes(w, bool)))
+        for w in windows:
+            for plan in plans:
+                for op in ("count", "range"):
+                    progs[(op, w, plan)] = (self._window_exec(op, plan), (st, lanes(w), lanes(w)))
+        for budget in maintain:
+            progs[("maintain", budget)] = (self._maintain_exec(budget), (st,))
+        if flush:
+            progs[("flush",)] = (self._flush_exec(), (st,))
+        if cleanup:
+            progs[("cleanup",)] = (self._cleanup_exec(), (st,))
+        with concurrent.futures.ThreadPoolExecutor(_COMPILE_THREADS) as pool:
+            futures = {name: pool.submit(lambda f, a: f.lower(*a).compile(), f, args)
+                       for name, (f, args) in progs.items()}
+            return {name: fut.result() for name, fut in futures.items()}
+
     # -- updates -------------------------------------------------------------
 
     def update(self, keys, values=None, is_delete=None, valid=None) -> "Dictionary":
@@ -382,12 +479,7 @@ class Dictionary:
         if valid is not None:
             valid = jnp.asarray(valid, bool)
 
-        f = _cached_exec(
-            self._backend, "update", _exec_update,
-            donate_state=True,
-            statics=(self._flush_threshold, self._maintenance_budget),
-        )
-        new_state = f(self._state, keys, values, is_delete, valid)
+        new_state = self._update_exec()(self._state, keys, values, is_delete, valid)
         return self._evolve(new_state)
 
     def insert(self, keys, values, valid=None) -> "Dictionary":
@@ -412,14 +504,14 @@ class Dictionary:
         self._require("bulk_build", self._backend.caps.supports_bulk_build)
         if self._validate:
             _check_key_domain("bulk_build keys", keys)
-        keys = _as_keys("keys", keys)
         if self._validate and _is_concrete(keys):
             arr = np.asarray(keys)
             if len(np.unique(arr)) != arr.shape[0]:
                 raise ValueError("bulk_build requires unique keys (paper §5.2)")
-        values = jnp.asarray(values, jnp.int32)
-        f = _cached_exec(self._backend, "bulk_build", _exec_bulk_build)
-        return self._evolve(f(keys, values))
+        placement = self._backend.input_sharding
+        keys = _as_keys("keys", keys, placement)
+        values = _as_keys("values", values, placement)
+        return self._evolve(self._bulk_build_exec()(keys, values))
 
     def cleanup(self) -> "Dictionary":
         """Purge stale elements and tombstones (paper §3.6/§4.5).
@@ -428,8 +520,7 @@ class Dictionary:
         cleanup-boundary flush) — afterwards `pending()` is 0 and no batch
         slot was wasted on a partial batch."""
         self._require("cleanup", self._backend.caps.supports_cleanup)
-        f = _cached_exec(self._backend, "cleanup", _exec_cleanup, donate_state=True)
-        return self._evolve(f(self._state))
+        return self._evolve(self._cleanup_exec()(self._state))
 
     def maintain(self, budget: Optional[int] = None) -> "Dictionary":
         """Budgeted incremental compaction: reclaim stale elements touching at
@@ -445,17 +536,7 @@ class Dictionary:
         Returns the new handle (the old one's buffers are donated).
         """
         self._require("maintain", self._backend.caps.supports_maintenance)
-        if budget is None:
-            budget = self._maintenance_budget
-        else:
-            budget = int(budget)
-            if budget < 1:
-                raise ValueError(f"maintain budget must be >= 1, got {budget}")
-        f = _cached_exec(
-            self._backend, "maintain", _exec_maintain,
-            donate_state=True, statics=(budget,),
-        )
-        return self._evolve(f(self._state))
+        return self._evolve(self._maintain_exec(budget)(self._state))
 
     def flush(self) -> "Dictionary":
         """Push staged (write-buffer) updates into the main structure.
@@ -464,11 +545,7 @@ class Dictionary:
         partial buffer is placebo-padded to a full batch, consuming one batch
         slot — the cost the coalescing update path defers. Returns the new
         handle (the old one's buffers are donated)."""
-        f = _cached_exec(
-            self._backend, "flush", _exec_flush,
-            donate_state=True, statics=(self._maintenance_budget,),
-        )
-        return self._evolve(f(self._state))
+        return self._evolve(self._flush_exec()(self._state))
 
     def pending(self):
         """Staged-but-unflushed element count (int32 scalar; 0 if unbuffered).
@@ -507,8 +584,7 @@ class Dictionary:
         if self._validate:
             _check_key_domain("lookup keys", keys)
         keys = _as_keys("keys", keys)
-        f = _cached_exec(self._backend, "lookup", _exec_lookup)
-        return f(self._state, keys)
+        return self._lookup_exec()(self._state, keys)
 
     def _resolved_plan(self, plan: Optional[QueryPlan]) -> QueryPlan:
         return (plan or QueryPlan()).resolved(self._backend.max_query_candidates)
@@ -524,9 +600,7 @@ class Dictionary:
             _check_key_domain("count k1", k1)
             _check_key_domain("count k2", k2)
         k1, k2 = _as_keys("k1", k1), _as_keys("k2", k2)
-        p = self._resolved_plan(plan)
-        f = _cached_exec(self._backend, "count", _exec_count, statics=(p,))
-        return f(self._state, k1, k2)
+        return self._window_exec("count", plan)(self._state, k1, k2)
 
     def range(self, k1, k2, plan: Optional[QueryPlan] = None):
         """RANGE(k1, k2) -> (keys [nq, max_results], values, counts, ok).
@@ -538,9 +612,7 @@ class Dictionary:
             _check_key_domain("range k1", k1)
             _check_key_domain("range k2", k2)
         k1, k2 = _as_keys("k1", k1), _as_keys("k2", k2)
-        p = self._resolved_plan(plan)
-        f = _cached_exec(self._backend, "range", _exec_range, statics=(p,))
-        return f(self._state, k1, k2)
+        return self._window_exec("range", plan)(self._state, k1, k2)
 
     def size(self):
         """Live (visible) element count, int32 scalar (stale excluded)."""

@@ -52,8 +52,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.core import semantics as sem
 from repro.core.cleanup import lsm_cleanup, lsm_maintain
 from repro.core.lsm import (
@@ -101,11 +99,13 @@ def dist_lsm_init(cfg: DistLSMConfig, mesh) -> LSMState:
     """Per-shard LSM states, stacked on a leading sharded axis."""
     from repro.dist.sharding import stacked_shardings
 
-    def init_one(_):
-        return lsm_init(cfg.local)
+    def init_all():
+        return jax.vmap(lambda _: lsm_init(cfg.local))(jnp.arange(cfg.num_shards))
 
-    states = jax.vmap(init_one)(jnp.arange(cfg.num_shards))
-    return jax.device_put(states, stacked_shardings(states, mesh, cfg.axis))
+    # Built in place: each device fills only its own shard. Built eagerly
+    # and then placed, all num_shards arenas would first sit on device 0.
+    shardings = stacked_shardings(jax.eval_shape(init_all), mesh, cfg.axis)
+    return jax.jit(init_all, out_shardings=shardings)()
 
 
 def _local_state(stacked: LSMState) -> LSMState:
@@ -137,7 +137,7 @@ def dist_update(cfg: DistLSMConfig, mesh, states, key_vars, values) -> LSMState:
         st = lsm_update(cfg.local, st, kv, val)
         return _restack(st)
 
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(state_spec, P(), P()),
         out_specs=state_spec,
@@ -167,7 +167,7 @@ def dist_stage(cfg: DistLSMConfig, mesh, states, key_vars, values, count) -> LSM
         st = lsm_stage(cfg.local, st, kv, val, cnt)
         return _restack(st)
 
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(state_spec, P(), P(), P()),
         out_specs=state_spec,
@@ -186,7 +186,7 @@ def dist_flush(cfg: DistLSMConfig, mesh, states, min_pending: int = 1) -> LSMSta
     def body(states):
         return _restack(lsm_flush(cfg.local, _local_state(states), min_pending))
 
-    f = shard_map(body, mesh=mesh, in_specs=(state_spec,), out_specs=state_spec,
+    f = jax.shard_map(body, mesh=mesh, in_specs=(state_spec,), out_specs=state_spec,
                   check_vma=False)
     return f(states)
 
@@ -198,7 +198,7 @@ def dist_pending(cfg: DistLSMConfig, mesh, states):
     def body(states):
         return jax.lax.psum(_local_state(states).buf_n, cfg.axis)
 
-    f = shard_map(body, mesh=mesh, in_specs=(state_spec,), out_specs=P(),
+    f = jax.shard_map(body, mesh=mesh, in_specs=(state_spec,), out_specs=P(),
                   check_vma=False)
     return f(states)
 
@@ -217,7 +217,7 @@ def dist_occupancy(cfg: DistLSMConfig, mesh, states):
         debt = jax.lax.psum(lsm_debt(cfg.local, local), cfg.axis)
         return pending, resident, debt
 
-    f = shard_map(body, mesh=mesh, in_specs=(state_spec,),
+    f = jax.shard_map(body, mesh=mesh, in_specs=(state_spec,),
                   out_specs=(P(), P(), P()), check_vma=False)
     return f(states)
 
@@ -233,7 +233,7 @@ def dist_flush_cost(cfg: DistLSMConfig, mesh, states):
             lsm_flush_cost(cfg.local, _local_state(states)), cfg.axis
         )
 
-    f = shard_map(body, mesh=mesh, in_specs=(state_spec,), out_specs=P(),
+    f = jax.shard_map(body, mesh=mesh, in_specs=(state_spec,), out_specs=P(),
                   check_vma=False)
     return f(states)
 
@@ -256,7 +256,7 @@ def dist_lookup(cfg: DistLSMConfig, mesh, states, keys):
         vals = jax.lax.psum(vals, cfg.axis)
         return found[None], vals[None]
 
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(state_spec, P()),
         out_specs=(P(), P()),
@@ -289,7 +289,7 @@ def dist_count(cfg: DistLSMConfig, mesh, states, k1, k2, max_candidates: int):
         ok = jax.lax.pmin(ok.astype(jnp.int32), cfg.axis) > 0
         return counts[None], ok[None]
 
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(state_spec, P(), P()),
         out_specs=(P(), P()),
@@ -325,7 +325,7 @@ def dist_range(cfg: DistLSMConfig, mesh, states, k1, k2,
         ok = jax.lax.pmin(ok.astype(jnp.int32), cfg.axis) > 0
         return keys[None], vals[None], counts[None], ok[None]
 
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(state_spec, P(), P()),
         out_specs=(state_spec, state_spec, state_spec, P()),
@@ -368,7 +368,7 @@ def dist_cleanup(cfg: DistLSMConfig, mesh, states) -> LSMState:
     def body(states):
         return _restack(lsm_cleanup(cfg.local, _local_state(states)))
 
-    f = shard_map(body, mesh=mesh, in_specs=(state_spec,), out_specs=state_spec,
+    f = jax.shard_map(body, mesh=mesh, in_specs=(state_spec,), out_specs=state_spec,
                   check_vma=False)
     return f(states)
 
@@ -393,7 +393,7 @@ def dist_maintain(
                          only_if_debt=only_if_debt)
         )
 
-    f = shard_map(body, mesh=mesh, in_specs=(state_spec,), out_specs=state_spec,
+    f = jax.shard_map(body, mesh=mesh, in_specs=(state_spec,), out_specs=state_spec,
                   check_vma=False)
     return f(states)
 
@@ -411,7 +411,7 @@ def dist_size(cfg: DistLSMConfig, mesh, states):
         local = valid_count_runs(all_runs(cfg.local, st))
         return jax.lax.psum(local, cfg.axis)
 
-    f = shard_map(body, mesh=mesh, in_specs=(state_spec,), out_specs=P(),
+    f = jax.shard_map(body, mesh=mesh, in_specs=(state_spec,), out_specs=P(),
                   check_vma=False)
     return f(states)
 
@@ -458,7 +458,7 @@ def dist_bulk_build(cfg: DistLSMConfig, mesh, keys, values) -> LSMState:
         )
         return _restack(st)
 
-    f = shard_map(body, mesh=mesh, in_specs=(P(), P()), out_specs=state_spec,
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()), out_specs=state_spec,
                   check_vma=False)
     return f(keys, values)
 

@@ -26,20 +26,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 def _ambient_mesh():
-    """The mesh of the enclosing `with mesh:` scope, or None."""
-    try:  # modern jax: explicit-sharding aware accessor
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except AttributeError:
-        pass
-    try:  # classic thread-resources env (jax <= 0.4.x and still-supported)
-        mesh = jax.interpreters.pxla.thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except AttributeError:
-        pass
-    return None
+    """The mesh of the enclosing `jax.set_mesh(...)` scope, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _clean_entry(mesh, entry, dim: int):

@@ -3,8 +3,9 @@
 The paper uses CUB radix sort. Radix sort is scatter-heavy (per-pass bucket
 scatters), which is hostile to the TPU's vector memory; the TPU-idiomatic
 equivalent of "fast device sort of a VMEM-resident tile" is a bitonic
-compare-exchange network: every stage is a branch-free reshape + min/max over
-lanes — zero gathers, zero scatters, perfect for the 8x128 VPU.
+compare-exchange network: every stage is a branch-free pair of rotations
+(lane or sublane, by the partner distance) + selects — zero gathers, zero
+scatters, perfect for the 8x128 VPU.
 
 The kernel sorts CHUNK-sized tiles entirely inside VMEM (grid over tiles).
 Arbitrarily large batches are handled in ops.py by a hierarchical sort:
@@ -22,53 +23,70 @@ is unspecified by semantics item 4).
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 CHUNK = 1 << 10          # elements sorted in one VMEM tile
 MIN_N = 8
-_INT32_MAX = jnp.iinfo(jnp.int32).max
+_LANES = 128
 
 
-def _compare_exchange(kv, val, j, k, n):
-    """One bitonic stage: partner distance j within ascending-by-bit-k runs."""
-    m = n // (2 * j)
-    kv3 = kv.reshape(m, 2, j)
-    val3 = val.reshape(m, 2, j)
-    a_kv, b_kv = kv3[:, 0, :], kv3[:, 1, :]
-    a_val, b_val = val3[:, 0, :], val3[:, 1, :]
+def _partner(x, j, cols):
+    """Value at flat index i ^ j, for a tile laid out [rows, cols] row-major.
+
+    Partners closer than a row sit in the same row (lane rotation); farther
+    ones sit j // cols rows away (sublane rotation). The lower lane of each
+    pair (bit j clear) reads forward, the upper lane backward.
+    """
+    rows = x.shape[0]
+    if j < cols:
+        fwd, back, axis = cols - j, j, 1
+    else:
+        fwd, back, axis = rows - j // cols, j // cols, 0
+    return pltpu.roll(x, fwd, axis), pltpu.roll(x, back, axis)
+
+
+def _compare_exchange(kv, val, flat, j, k):
+    """One bitonic stage: partner distance j within ascending-by-bit-k runs.
+
+    Each lane reads its partner (i ^ j) by rotation and keeps its own or the
+    partner's pair; the swap rule is evaluated once per pair, as seen from
+    the lower lane `a` with its upper partner `b`.
+    """
+    cols = kv.shape[1]
+    lower = (flat & j) == 0
     # Direction bit: ascending iff (flat_index & k) == 0; constant across the
-    # pair (j < k), so evaluate it at the `a` element.
-    flat_a = (
-        jnp.arange(m, dtype=jnp.int32)[:, None] * (2 * j)
-        + jnp.arange(j, dtype=jnp.int32)[None, :]
-    )
-    asc = (flat_a & k) == 0
+    # pair (j < k).
+    asc = (flat & k) == 0
+    kv_f, kv_b = _partner(kv, j, cols)
+    val_f, val_b = _partner(val, j, cols)
+    p_kv = jnp.where(lower, kv_f, kv_b)
+    p_val = jnp.where(lower, val_f, val_b)
+    a_kv = jnp.where(lower, kv, p_kv)
+    b_kv = jnp.where(lower, p_kv, kv)
     swap = (a_kv > b_kv) == asc  # out of order w.r.t. direction
-    new_a_kv = jnp.where(swap, b_kv, a_kv)
-    new_b_kv = jnp.where(swap, a_kv, b_kv)
-    new_a_val = jnp.where(swap, b_val, a_val)
-    new_b_val = jnp.where(swap, a_val, b_val)
-    kv3 = jnp.stack([new_a_kv, new_b_kv], axis=1)
-    val3 = jnp.stack([new_a_val, new_b_val], axis=1)
-    return kv3.reshape(n), val3.reshape(n)
+    return jnp.where(swap, p_kv, kv), jnp.where(swap, p_val, val)
 
 
-def _bitonic_kernel(x_ref, o_ref, *, n):
-    kv = x_ref[0, :]
-    val = x_ref[1, :]
+def _bitonic_kernel(kv_ref, val_ref, okv_ref, oval_ref, *, n):
+    kv = kv_ref[...]
+    val = val_ref[...]
+    flat = (
+        jax.lax.broadcasted_iota(jnp.int32, kv.shape, 0) * kv.shape[1]
+        + jax.lax.broadcasted_iota(jnp.int32, kv.shape, 1)
+    )
     k = 2
     while k <= n:
         j = k // 2
         while j >= 1:
-            kv, val = _compare_exchange(kv, val, j, k, n)
+            kv, val = _compare_exchange(kv, val, flat, j, k)
             j //= 2
         k *= 2
-    o_ref[0, :] = kv
-    o_ref[1, :] = val
+    okv_ref[...] = kv
+    oval_ref[...] = val
 
 
 def bitonic_sort_pairs(key_vars, values, *, interpret=False):
@@ -82,30 +100,37 @@ def bitonic_sort_pairs(key_vars, values, *, interpret=False):
     assert n & (n - 1) == 0 and n >= MIN_N, n
     tile = min(n, CHUNK)
     n_tiles = n // tile
-    stacked = jnp.stack([key_vars.astype(jnp.int32), values.astype(jnp.int32)])
-    out = pl.pallas_call(
+    # Each tile is laid out [rows, cols] row-major: (8, 128) — one vreg — for
+    # a full CHUNK, a single row for tiny sorts.
+    cols = min(tile, _LANES)
+    rows = tile // cols
+    block = pl.BlockSpec((rows, cols), lambda i: (i, 0))
+    shape = jax.ShapeDtypeStruct((n // cols, cols), jnp.int32)
+    kv, val = pl.pallas_call(
         functools.partial(_bitonic_kernel, n=tile),
         grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((2, tile), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((2, tile), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((2, n), jnp.int32),
+        in_specs=[block, block],
+        out_specs=[block, block],
+        out_shape=[shape, shape],
+        name="lsm_bitonic_sort",
         interpret=interpret,
-    )(stacked)
-    kv, val = out[0], out[1]
+    )(
+        key_vars.astype(jnp.int32).reshape(n // cols, cols),
+        values.astype(jnp.int32).reshape(n // cols, cols),
+    )
+    kv, val = kv.reshape(n), val.reshape(n)
     if n_tiles > 1:
         from repro.kernels import merge_path
 
-        # Hierarchical combine: pairwise compare-full Merge-Path rounds.
-        runs = [(kv[i * tile : (i + 1) * tile], val[i * tile : (i + 1) * tile]) for i in range(n_tiles)]
-        while len(runs) > 1:
-            nxt = []
-            for i in range(0, len(runs), 2):
-                a, b = runs[i], runs[i + 1]
-                nxt.append(
-                    merge_path.merge_path(
-                        a[0], a[1], b[0], b[1], compare_full=True, interpret=interpret
-                    )
-                )
-            runs = nxt
-        kv, val = runs[0]
+        # Hierarchical combine: rounds of compare-full Merge Path, each round
+        # merging every adjacent pair of sorted runs in one batched launch.
+        merge = functools.partial(
+            merge_path.merge_path, compare_full=True, interpret=interpret
+        )
+        width = tile
+        while width < n:
+            kv2, val2 = kv.reshape(-1, 2, width), val.reshape(-1, 2, width)
+            kv, val = jax.vmap(merge)(kv2[:, 0], val2[:, 0], kv2[:, 1], val2[:, 1])
+            kv, val = kv.reshape(n), val.reshape(n)
+            width *= 2
     return kv, val

@@ -79,10 +79,9 @@ def _lower_bound_kernel(q_ref, chunk_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    q = q_ref[...]          # [QUERY_BLOCK]
-    keys = chunk_ref[...]   # [LEVEL_CHUNK]
-    cnt = jnp.sum((keys[None, :] < q[:, None]).astype(jnp.int32), axis=1)
-    o_ref[...] += cnt
+    q = q_ref[...]          # [QUERY_BLOCK, 1] — queries down the sublanes
+    keys = chunk_ref[...]   # [1, LEVEL_CHUNK] — keys across the lanes
+    o_ref[...] += jnp.sum((keys < q).astype(jnp.int32), axis=1, keepdims=True)
 
 
 def lower_bound_streamed(sorted_keys, query_keys, *, interpret=False):
@@ -90,22 +89,31 @@ def lower_bound_streamed(sorted_keys, query_keys, *, interpret=False):
 
     sorted_keys: int32[n], n % LEVEL_CHUNK == 0 (placebo-padded by the LSM).
     query_keys:  int32[q], q % QUERY_BLOCK == 0.
+
+    Both operands enter the kernel 2-D — queries as a [q, 1] column, keys as
+    a [1, n] row — because Mosaic tiles every VMEM block in (8, 128) vregs and
+    rejects 1-D blocks whose HBM layout disagrees with its own.
     """
     n = sorted_keys.shape[0]
     q = query_keys.shape[0]
     assert n % LEVEL_CHUNK == 0 and q % QUERY_BLOCK == 0, (n, q)
     grid = (q // QUERY_BLOCK, n // LEVEL_CHUNK)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _lower_bound_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((QUERY_BLOCK,), lambda i, c: (i,)),
-            pl.BlockSpec((LEVEL_CHUNK,), lambda i, c: (c,)),
+            pl.BlockSpec((QUERY_BLOCK, 1), lambda i, c: (i, 0)),
+            pl.BlockSpec((1, LEVEL_CHUNK), lambda i, c: (0, c)),
         ],
-        out_specs=pl.BlockSpec((QUERY_BLOCK,), lambda i, c: (i,)),
-        out_shape=jax.ShapeDtypeStruct((q,), jnp.int32),
+        out_specs=pl.BlockSpec((QUERY_BLOCK, 1), lambda i, c: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((q, 1), jnp.int32),
+        name="lsm_lower_bound",
         interpret=interpret,
-    )(query_keys.astype(jnp.int32), sorted_keys.astype(jnp.int32))
+    )(
+        query_keys.astype(jnp.int32).reshape(q, 1),
+        sorted_keys.astype(jnp.int32).reshape(1, n),
+    )
+    return out[:, 0]
 
 
 def _fused_lookup_kernel(q_ref, flat_hbm, okv_ref, oval_ref, *, n, chunk, depth):
@@ -113,19 +121,20 @@ def _fused_lookup_kernel(q_ref, flat_hbm, okv_ref, oval_ref, *, n, chunk, depth)
 
     flat_hbm stays in HBM (memory_space=ANY); `depth` revolving VMEM buffers
     overlap the DMA of chunk c+depth with the scan of chunk c. Per chunk the
-    scan is an all-pairs match matrix + first-match one-hot select — pure VPU
-    work against data that is read exactly once, so the kernel is
-    bandwidth-bound like the streamed lower_bound above, but issues ONE kernel
-    for all runs instead of one per run.
+    scan is an all-pairs match matrix (queries down the sublanes, chunk keys
+    across the lanes) and a first-match select: the masked minimum of a lane
+    iota names the lowest matching lane, and a one-hot on that lane picks its
+    key variable and value.
     """
     num_chunks = n // chunk
-    q = q_ref[...]                      # [query_block]
+    q = q_ref[...]                      # [query_block, 1]
     qb = q.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (qb, chunk), 1)
 
     def body(bufs, sems):
         def dma(c, slot):
             return pltpu.make_async_copy(
-                flat_hbm.at[:, pl.ds(c * chunk, chunk)],
+                flat_hbm.at[:, pl.ds(pl.multiple_of(c * chunk, chunk), chunk)],
                 bufs.at[slot],
                 sems.at[slot],
             )
@@ -137,14 +146,15 @@ def _fused_lookup_kernel(q_ref, flat_hbm, okv_ref, oval_ref, *, n, chunk, depth)
             best_kv, best_val = carry
             slot = jax.lax.rem(c, depth)
             dma(c, slot).wait()
-            ckv = bufs[slot, 0, :]
-            cval = bufs[slot, 1, :]
-            keys = ckv >> 1             # original keys (placebos stay maximal)
-            match = keys[None, :] == q[:, None]                      # [qb, chunk]
-            first = match & (jnp.cumsum(match.astype(jnp.int32), axis=1) == 1)
-            hit = jnp.sum(first.astype(jnp.int32), axis=1) > 0
-            sel_kv = jnp.sum(jnp.where(first, ckv[None, :], 0), axis=1)
-            sel_val = jnp.sum(jnp.where(first, cval[None, :], 0), axis=1)
+            buf = bufs[slot]            # [2, chunk]: kv row, value row
+            ckv = buf[0:1, :]
+            cval = buf[1:2, :]
+            match = (ckv >> 1) == q     # original keys; placebos stay maximal
+            first = jnp.min(jnp.where(match, lane, chunk), axis=1, keepdims=True)
+            hit = first < chunk
+            pick = lane == first
+            sel_kv = jnp.sum(jnp.where(pick, ckv, 0), axis=1, keepdims=True)
+            sel_val = jnp.sum(jnp.where(pick, cval, 0), axis=1, keepdims=True)
             # A query is unresolved while its best is still the placebo
             # sentinel: no real element ever encodes to PLACEBO_KV (user keys
             # are < PLACEBO_KEY), and a legitimate placebo "match" (query ==
@@ -162,8 +172,8 @@ def _fused_lookup_kernel(q_ref, flat_hbm, okv_ref, oval_ref, *, n, chunk, depth)
             return best_kv, best_val
 
         init = (
-            jnp.full((qb,), sem.PLACEBO_KV, dtype=jnp.int32),
-            jnp.full((qb,), sem.EMPTY_VALUE, dtype=jnp.int32),
+            jnp.full((qb, 1), sem.PLACEBO_KV, dtype=jnp.int32),
+            jnp.full((qb, 1), sem.EMPTY_VALUE, dtype=jnp.int32),
         )
         best_kv, best_val = jax.lax.fori_loop(0, num_chunks, step, init)
         okv_ref[...] = best_kv
@@ -206,20 +216,17 @@ def fused_lookup_runs(
         [jnp.asarray(flat_kv, jnp.int32), jnp.asarray(flat_val, jnp.int32)]
     )  # [2, n] — one DMA moves the kv and value rows of a chunk together
     grid = (q // query_block,)
-    return pl.pallas_call(
+    col = pl.BlockSpec((query_block, 1), lambda i: (i, 0))
+    best_kv, best_val = pl.pallas_call(
         functools.partial(_fused_lookup_kernel, n=n, chunk=chunk, depth=depth),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((query_block,), lambda i: (i,)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # streamed manually via DMA
-        ],
-        out_specs=[
-            pl.BlockSpec((query_block,), lambda i: (i,)),
-            pl.BlockSpec((query_block,), lambda i: (i,)),
-        ],
+        in_specs=[col, pl.BlockSpec(memory_space=pl.ANY)],  # flat: manual DMA
+        out_specs=[col, col],
         out_shape=[
-            jax.ShapeDtypeStruct((q,), jnp.int32),
-            jax.ShapeDtypeStruct((q,), jnp.int32),
+            jax.ShapeDtypeStruct((q, 1), jnp.int32),
+            jax.ShapeDtypeStruct((q, 1), jnp.int32),
         ],
+        name="lsm_fused_lookup",
         interpret=interpret,
-    )(query_keys.astype(jnp.int32), flat)
+    )(query_keys.astype(jnp.int32).reshape(q, 1), flat)
+    return best_kv[:, 0], best_val[:, 0]

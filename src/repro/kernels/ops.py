@@ -9,13 +9,15 @@ Backends:
              kernels execute in interpret mode (used by the test suite to
              validate the kernel bodies against the oracles).
 
-Selection: `set_backend(...)` or the REPRO_KERNEL_BACKEND env var.
+Selection: `set_backend(...)` or the REPRO_KERNEL_BACKEND env var. A shape
+that does not tile for a kernel takes the "xla" path even on the Pallas
+backend; `record_paths()` reports which path every dispatch took.
 """
 
 from __future__ import annotations
 
+import contextlib
 import os
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -23,8 +25,8 @@ import jax.numpy as jnp
 from repro.kernels import ref
 
 _BACKEND = os.environ.get("REPRO_KERNEL_BACKEND", "xla")
-# Pallas kernels run in interpret mode automatically off-TPU.
-_INTERPRET = jax.default_backend() != "tpu"
+# Open `record_paths()` logs; every dispatch appends (op, path) to each.
+_PATH_LOGS: list = []
 
 
 def set_backend(name: str) -> None:
@@ -38,66 +40,83 @@ def get_backend() -> str:
     return _BACKEND
 
 
-def _pallas_viable_merge(na: int, nb: int) -> bool:
-    from repro.kernels import merge_path
+def _interpret() -> bool:
+    """Pallas kernels compile for a TPU and run in interpret mode elsewhere.
 
+    Asked at dispatch (trace) time, not at import, so importing this module
+    never initializes a JAX backend."""
+    return jax.default_backend() != "tpu"
+
+
+@contextlib.contextmanager
+def record_paths():
+    """Collect `(op, path)` for every dispatch traced inside the block, path
+    one of "pallas", "pallas_interpret" or "xla".
+
+    Dispatch runs while a jitted program is traced, so a program that is
+    already compiled records nothing: open the block around its first call.
+    """
+    log: list = []
+    _PATH_LOGS.append(log)
+    try:
+        yield log
+    finally:
+        _PATH_LOGS.remove(log)
+
+
+def _took(op: str, kernel: bool) -> bool:
+    path = ("pallas_interpret" if _interpret() else "pallas") if kernel else "xla"
+    for log in _PATH_LOGS:
+        log.append((op, path))
+    return kernel
+
+
+def _pallas_viable_search(sorted_orig_keys, query_keys) -> bool:
+    from repro.kernels import lsm_lookup
+
+    n, q = sorted_orig_keys.shape[0], query_keys.shape[0]
     return (
-        na % merge_path.BLOCK == 0
-        and nb % merge_path.BLOCK == 0
-        and na >= merge_path.BLOCK
-        and nb >= merge_path.BLOCK
+        _BACKEND == "pallas"
+        and n % lsm_lookup.LEVEL_CHUNK == 0
+        and q % lsm_lookup.QUERY_BLOCK == 0
     )
-
-
-def merge_sorted(a_kv, a_val, b_kv, b_val):
-    """Stable original-key merge; `a` is the newer run (ties: a first)."""
-    if _BACKEND == "pallas" and _pallas_viable_merge(a_kv.shape[0], b_kv.shape[0]):
-        from repro.kernels import merge_path
-
-        return merge_path.merge_path(a_kv, a_val, b_kv, b_val, interpret=_INTERPRET)
-    return ref.merge_ref(a_kv, a_val, b_kv, b_val)
 
 
 def merge_cascade(runs):
     """K-way stable merge of sorted runs ordered NEWEST FIRST.
 
     runs: [(key_vars, values), ...]; ties on original key resolve to the
-    earliest (newest) run, within a run to the earliest index — identical to
-    a left fold of `merge_sorted` with the accumulated side as `a`.
+    earliest (newest) run, within a run to the earliest index.
 
     One binary-counter cascade step, a cleanup, and `valid_count_runs` are all
     K-way merges; on the Pallas backend they stream every element through VMEM
-    exactly once (`merge_path.merge_cascade_path`) instead of paying one HBM
-    round trip of the growing intermediate per fold step.
+    exactly once (`merge_path.merge_cascade_path`); on XLA they are one stable
+    sort (`ref.merge_cascade_ref`).
     """
     runs = [(jnp.asarray(kv, jnp.int32), jnp.asarray(v, jnp.int32)) for kv, v in runs]
     if len(runs) == 1:
         return runs[0]
-    if _BACKEND == "pallas":
-        from repro.kernels import merge_path
+    from repro.kernels import merge_path
 
-        if all(
-            kv.shape[0] % merge_path.BLOCK == 0 and kv.shape[0] >= merge_path.BLOCK
-            for kv, _ in runs
-        ):
-            return merge_path.merge_cascade_path(
-                [kv for kv, _ in runs], [v for _, v in runs], interpret=_INTERPRET
-            )
-    # XLA fold (pairwise merges may still pick the pairwise Pallas kernel).
-    out_kv, out_val = runs[0]
-    for kv, val in runs[1:]:
-        out_kv, out_val = merge_sorted(out_kv, out_val, kv, val)
-    return out_kv, out_val
+    viable = _BACKEND == "pallas" and all(
+        kv.shape[0] % merge_path.BLOCK == 0 and kv.shape[0] >= merge_path.BLOCK
+        for kv, _ in runs
+    )
+    if _took("merge_cascade", viable):
+        return merge_path.merge_cascade_path(
+            [kv for kv, _ in runs], [v for _, v in runs], interpret=_interpret()
+        )
+    return ref.merge_cascade_ref([kv for kv, _ in runs], [v for _, v in runs])
 
 
 def sort_pairs(key_vars, values):
     """Sort (key_var, value) pairs by full key variable, stable."""
-    if _BACKEND == "pallas":
-        from repro.kernels import bitonic_sort
+    from repro.kernels import bitonic_sort
 
-        n = key_vars.shape[0]
-        if n >= bitonic_sort.MIN_N and (n & (n - 1)) == 0:
-            return bitonic_sort.bitonic_sort_pairs(key_vars, values, interpret=_INTERPRET)
+    n = key_vars.shape[0]
+    viable = _BACKEND == "pallas" and n >= bitonic_sort.MIN_N and (n & (n - 1)) == 0
+    if _took("sort", viable):
+        return bitonic_sort.bitonic_sort_pairs(key_vars, values, interpret=_interpret())
     return ref.sort_ref(key_vars, values)
 
 
@@ -125,14 +144,12 @@ def sort_pairs_recency(key_vars, values):
 
 def lower_bound(sorted_orig_keys, query_keys):
     """Vectorized lower-bound (first index with key >= query)."""
-    if _BACKEND == "pallas":
+    if _took("lower_bound", _pallas_viable_search(sorted_orig_keys, query_keys)):
         from repro.kernels import lsm_lookup
 
-        n, q = sorted_orig_keys.shape[0], query_keys.shape[0]
-        if n % lsm_lookup.LEVEL_CHUNK == 0 and q % lsm_lookup.QUERY_BLOCK == 0:
-            return lsm_lookup.lower_bound_streamed(
-                sorted_orig_keys, query_keys, interpret=_INTERPRET
-            )
+        return lsm_lookup.lower_bound_streamed(
+            sorted_orig_keys, query_keys, interpret=_interpret()
+        )
     return ref.lower_bound_ref(sorted_orig_keys, query_keys)
 
 
@@ -145,17 +162,16 @@ def upper_bound(sorted_orig_keys, query_keys):
     can store (user keys plus the placebo key, all < 2**30) compares <= such
     a query, so the answer is simply n.
     """
-    if _BACKEND == "pallas":
+    if _took("upper_bound", _pallas_viable_search(sorted_orig_keys, query_keys)):
         from repro.kernels import lsm_lookup
 
-        n, q = sorted_orig_keys.shape[0], query_keys.shape[0]
-        if n % lsm_lookup.LEVEL_CHUNK == 0 and q % lsm_lookup.QUERY_BLOCK == 0:
-            qk = jnp.asarray(query_keys, jnp.int32)
-            safe = qk < jnp.iinfo(jnp.int32).max
-            lo = lsm_lookup.lower_bound_streamed(
-                sorted_orig_keys, jnp.where(safe, qk + 1, qk), interpret=_INTERPRET
-            )
-            return jnp.where(safe, lo, jnp.asarray(n, jnp.int32))
+        n = sorted_orig_keys.shape[0]
+        qk = jnp.asarray(query_keys, jnp.int32)
+        safe = qk < jnp.iinfo(jnp.int32).max
+        lo = lsm_lookup.lower_bound_streamed(
+            sorted_orig_keys, jnp.where(safe, qk + 1, qk), interpret=_interpret()
+        )
+        return jnp.where(safe, lo, jnp.asarray(n, jnp.int32))
     return ref.upper_bound_ref(sorted_orig_keys, query_keys)
 
 
@@ -169,7 +185,7 @@ def lookup_runs_fused(runs, query_keys):
     None when not selected — the caller (core/queries.py::lookup_runs) falls
     back to the per-run resolution loop.
     """
-    if _BACKEND != "pallas":
+    if not _took("lookup", _BACKEND == "pallas"):
         return None
     from repro.core import semantics as sem
     from repro.kernels import lsm_lookup
@@ -187,7 +203,7 @@ def lookup_runs_fused(runs, query_keys):
     pad_q = -nq % qb
     qk_padded = jnp.concatenate([qk, jnp.full((pad_q,), sem.PLACEBO_KEY, jnp.int32)]) if pad_q else qk
     best_kv, best_val = lsm_lookup.fused_lookup_runs(
-        flat_kv, flat_val, qk_padded, interpret=_INTERPRET
+        flat_kv, flat_val, qk_padded, interpret=_interpret()
     )
     best_kv, best_val = best_kv[:nq], best_val[:nq]
     hit = sem.original_key(best_kv) == qk

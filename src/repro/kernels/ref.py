@@ -2,9 +2,9 @@
 
 These are the semantic ground truth for the Pallas kernels (merge_path,
 bitonic_sort, lsm_lookup) and also serve as the XLA fallback path used on
-platforms without Pallas support (e.g. this CPU container outside of
-interpret-mode tests). Everything here is O(n log n) rank-based and fully
-parallel, so the fallback is itself production-quality XLA.
+platforms without Pallas support (the CPU, outside interpret-mode tests).
+Everything here is a sort or a binary search, with no data-dependent
+control flow.
 """
 
 from __future__ import annotations
@@ -15,42 +15,29 @@ import jax.numpy as jnp
 from repro.core import semantics as sem
 
 
-def merge_ref(a_kv, a_val, b_kv, b_val):
-    """Stable merge of two sorted runs, comparing ORIGINAL keys only.
-
-    `a` is the NEWER run: for equal original keys, all of `a`'s elements
-    precede all of `b`'s in the output (paper §4.1 — "new levels merged into
-    existing levels appear first in the merged result"). Within each run the
-    input order is preserved.
-
-    Rank-based formulation: element a[i] lands at i + |{j : b_key[j] < a_key[i]}|,
-    element b[j] lands at j + |{i : a_key[i] <= b_key[j]}|. Both scatters are
-    disjoint and cover [0, |a|+|b|).
-    """
-    a_keys = sem.original_key(a_kv)
-    b_keys = sem.original_key(b_kv)
-    na, nb = a_keys.shape[0], b_keys.shape[0]
-    idx_a = jnp.arange(na, dtype=jnp.int32) + jnp.searchsorted(b_keys, a_keys, side="left").astype(jnp.int32)
-    idx_b = jnp.arange(nb, dtype=jnp.int32) + jnp.searchsorted(a_keys, b_keys, side="right").astype(jnp.int32)
-    out_kv = jnp.zeros(na + nb, dtype=a_kv.dtype)
-    out_val = jnp.zeros(na + nb, dtype=a_val.dtype)
-    out_kv = out_kv.at[idx_a].set(a_kv).at[idx_b].set(b_kv)
-    out_val = out_val.at[idx_a].set(a_val).at[idx_b].set(b_val)
-    return out_kv, out_val
-
-
 def merge_cascade_ref(runs_kv, runs_val):
-    """K-way stable newest-first merge as a left fold of pairwise merges.
+    """K-way stable merge of sorted runs ordered NEWEST first, comparing
+    ORIGINAL keys only.
 
-    The pairwise merge is associative under the newest-first tie rule (the
-    accumulated side is always the newer one), so the fold is element-for-
-    element identical to a true K-way priority merge — this is the semantic
-    oracle for `merge_path.merge_cascade_path`.
+    For equal original keys, elements of earlier (newer) runs precede those
+    of later runs (paper §4.1 — "new levels merged into existing levels
+    appear first in the merged result"); within a run, input order holds.
+    That is exactly a stable sort of the runs concatenated newest first, so
+    this is one `lax.sort` — no gathers or scatters, which the TPU executes
+    slowly at the paper's sizes. It is the semantic oracle for
+    `merge_path.merge_cascade_path`.
     """
-    out_kv, out_val = runs_kv[0], runs_val[0]
-    for kv, val in zip(runs_kv[1:], runs_val[1:]):
-        out_kv, out_val = merge_ref(out_kv, out_val, kv, val)
-    return out_kv, out_val
+    kv = jnp.concatenate([jnp.asarray(x, jnp.int32) for x in runs_kv])
+    val = jnp.concatenate([jnp.asarray(x, jnp.int32) for x in runs_val])
+    _, kv, val = jax.lax.sort(
+        (sem.original_key(kv), kv, val), dimension=0, is_stable=True, num_keys=1
+    )
+    return kv, val
+
+
+def merge_ref(a_kv, a_val, b_kv, b_val):
+    """Stable merge of two sorted runs; `a` is the NEWER run."""
+    return merge_cascade_ref([a_kv, b_kv], [a_val, b_val])
 
 
 def fused_lookup_ref(flat_kv, flat_val, query_keys):

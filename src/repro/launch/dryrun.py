@@ -189,7 +189,7 @@ _WHILE_RE = re.compile(r"=\s*\([^)]*\)\s*while\(|=\s*[a-z0-9]+\[[0-9,]*\][^ ]*\s
 def _compile_and_measure(arch, shape_name, mesh, options):
     """One compile -> (cfg, shape, flops, bytes, coll_bytes, per_op, ma, has_loop)."""
     cfg, shape, jitted, args = build_cell(arch, shape_name, mesh, options=options)
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*args)
         compiled = lowered.compile()
     ca = compiled.cost_analysis()

@@ -2,7 +2,7 @@
 
 Also owns the 1-D dictionary-shard mesh used by the `lsm_sharded` backend
 (repro.api.backends): backends never call jax.make_mesh directly — mesh
-construction and version shims stay in launch/ + repro.compat.
+construction stays in launch/.
 """
 
 from __future__ import annotations
@@ -10,8 +10,8 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -17,6 +17,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 import numpy as np
 
 from repro.checkpoint.checkpoint import CheckpointManager
@@ -36,9 +37,7 @@ def best_fit_mesh():
         if n % m == 0 and m <= n:
             model = m
             break
-    from repro.compat import AxisType, make_mesh
-
-    return make_mesh(
+    return jax.make_mesh(
         (n // model, model), ("data", "model"),
         axis_types=(AxisType.Auto,) * 2,
     )

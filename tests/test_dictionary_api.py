@@ -364,3 +364,44 @@ class TestFacadeMechanics:
         # One more element forces a flush past the last batch slot: latched.
         d = d.insert(np.asarray([9]), np.zeros(1, np.int32))
         assert bool(d.overflowed())
+
+    @pytest.mark.parametrize("backend", ["lsm", "lsm_sharded"])
+    def test_precompile_serves_the_later_calls(self, backend):
+        """precompile() builds the very programs the methods run: the calls
+        it prepared compile nothing, and their answers are right."""
+        from jax import monitoring
+
+        extra = {"num_shards": 4} if backend == "lsm_sharded" else {}
+        d = Dictionary.create(backend, batch_size=B, num_levels=4, **extra)
+        plan = QueryPlan(max_candidates=64, max_results=16)
+        keys = np.arange(0, 60, 3, dtype=np.int32)
+        compiled = d.precompile(bulk=len(keys), lookups=[16], updates=[B], windows=[4],
+                                plans=[plan], maintain=[32], flush=True, cleanup=True)
+        assert len(compiled) == 8
+        compiles = []
+
+        def on_duration(name, secs, **_):
+            if name == "/jax/core/compile/backend_compile_duration":
+                compiles.append(name)
+
+        q = np.arange(16, dtype=np.int32)
+        k1, k2 = np.asarray([0, 10, 20, 50], np.int32), np.asarray([9, 30, 20, 59], np.int32)
+        monitoring.register_event_duration_secs_listener(on_duration)
+        try:
+            d = d.bulk_build(keys, keys * 2)
+            d = d.update(np.full(B, 7, np.int32), np.full(B, 70, np.int32),
+                         is_delete=np.zeros(B, bool), valid=np.arange(B) < 1)
+            found, vals = d.lookup(q)
+            counts, _ = d.count(k1, k2, plan)
+            d = d.flush().maintain(32).cleanup()
+            rkeys, _, rcounts, _ = d.range(k1, k2, plan)
+            found, vals, counts, rkeys, rcounts = jax.device_get((found, vals, counts, rkeys, rcounts))
+        finally:
+            monitoring.unregister_event_duration_listener(on_duration)
+        assert compiles == []
+        live = {int(k): 2 * int(k) for k in keys} | {7: 70}
+        assert found.tolist() == [k in live for k in q.tolist()]
+        assert vals.tolist() == [live.get(k, 0) for k in q.tolist()]
+        expect = [sum(a <= k <= b for k in live) for a, b in zip(k1.tolist(), k2.tolist())]
+        assert counts.tolist() == expect and rcounts.tolist() == expect
+        assert rkeys[1, :expect[1]].tolist() == sorted(k for k in live if 10 <= k <= 30)

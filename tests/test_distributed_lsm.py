@@ -96,9 +96,9 @@ class TestOwnerOf:
 def setup():
     # Function-scoped: make_dist_update donates its state argument, so every
     # test needs fresh buffers.
-    from repro.compat import AxisType, make_mesh
+    from jax.sharding import AxisType
 
-    mesh = make_mesh((4,), ("shard",), axis_types=(AxisType.Auto,))
+    mesh = jax.make_mesh((4,), ("shard",), axis_types=(AxisType.Auto,))
     cfg = DistLSMConfig(local=LSMConfig(batch_size=B, num_levels=4), num_shards=4)
     states = dist_lsm_init(cfg, mesh)
     return mesh, cfg, states
@@ -196,3 +196,33 @@ def test_dist_cleanup_local_and_transparent(setup):
     f2, v2 = lookup(states, q)
     np.testing.assert_array_equal(np.asarray(f1), np.asarray(f2))
     np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
+
+
+def _bulk_keys(n):
+    rng = np.random.default_rng(3)
+    return np.sort(rng.choice(sem.MAX_USER_KEY, n, replace=False)).astype(np.int32)
+
+
+@NEEDS_DEVICES
+def test_dist_bulk_build_fills_one_shards_capacity():
+    """A bulk build takes up to the per-shard capacity (one shard may own
+    every key); at exactly that size every key is found."""
+    from repro.api import Dictionary
+
+    d = Dictionary.create("lsm_sharded", num_shards=4, batch_size=256, num_levels=2)
+    keys = _bulk_keys(d.capacity)
+    d = d.bulk_build(keys, keys % 1000)
+    found, vals = d.lookup(keys)
+    assert not bool(d.overflowed())
+    assert bool(np.asarray(found).all())
+    np.testing.assert_array_equal(np.asarray(vals), keys % 1000)
+
+
+@NEEDS_DEVICES
+def test_dist_bulk_build_refuses_more_than_one_shards_capacity():
+    from repro.api import Dictionary
+
+    d = Dictionary.create("lsm_sharded", num_shards=4, batch_size=256, num_levels=2)
+    keys = _bulk_keys(d.capacity + 1)
+    with pytest.raises(ValueError, match="per-shard capacity"):
+        d.bulk_build(keys, keys % 1000)

@@ -87,11 +87,9 @@ class TestCompression:
     def test_compressed_psum_matches_mean(self):
         if len(jax.devices()) < 1:
             pytest.skip("needs a device")
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import AxisType, PartitionSpec as P
 
-        from repro.compat import AxisType, make_mesh, shard_map
-
-        mesh = make_mesh((1,), ("d",), axis_types=(AxisType.Auto,))
+        mesh = jax.make_mesh((1,), ("d",), axis_types=(AxisType.Auto,))
         g = jax.random.normal(jax.random.PRNGKey(0), (64,), jnp.float32)
         tree = {"g": g}
         err = init_error_state(tree)
@@ -99,7 +97,7 @@ class TestCompression:
         def body(t, e):
             return compressed_tree_psum(t, "d", e)
 
-        f = shard_map(body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
+        f = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
                       check_vma=False)
         mean, new_err = f(tree, err)
         # single shard: mean == dequantized value; error feedback captures residual
@@ -112,14 +110,12 @@ class TestCompression:
 
     def test_error_feedback_converges(self):
         """Repeated compression of a constant gradient averages to the truth."""
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import AxisType, PartitionSpec as P
 
-        from repro.compat import AxisType, make_mesh, shard_map
-
-        mesh = make_mesh((1,), ("d",), axis_types=(AxisType.Auto,))
+        mesh = jax.make_mesh((1,), ("d",), axis_types=(AxisType.Auto,))
         g = {"g": jnp.asarray([0.001, -1.0, 0.5, 0.3333], jnp.float32)}
         err = init_error_state(g)
-        f = shard_map(lambda t, e: compressed_tree_psum(t, "d", e), mesh=mesh,
+        f = jax.shard_map(lambda t, e: compressed_tree_psum(t, "d", e), mesh=mesh,
                       in_specs=(P(), P()), out_specs=(P(), P()), check_vma=False)
         acc = np.zeros(4, np.float32)
         for i in range(64):

@@ -63,9 +63,10 @@ def test_merge_partition_boundaries():
     a = jnp.array([1, 3, 5, 7], jnp.int32)
     b = jnp.array([2, 4, 6, 8], jnp.int32)
     d = jnp.arange(9, dtype=jnp.int32)
-    bounds = np.asarray(merge_path.merge_partition(a, b, d))
+    bounds = np.asarray(merge_path.cascade_partition([a, b], d))
     # merged: 1 2 3 4 5 6 7 8 -> a-counts 0 1 1 2 2 3 3 4 4
-    np.testing.assert_array_equal(bounds, [0, 1, 1, 2, 2, 3, 3, 4, 4])
+    np.testing.assert_array_equal(bounds[0], [0, 1, 1, 2, 2, 3, 3, 4, 4])
+    np.testing.assert_array_equal(bounds[1], np.arange(9) - bounds[0])
 
 
 # ---------------------------------------------------------------------------
@@ -176,3 +177,21 @@ def test_lsm_update_with_pallas_backend_matches_xla():
     f2, v2 = lsm_lookup_fn(cfg, states["pallas"], q)
     np.testing.assert_array_equal(np.asarray(f1), np.asarray(f2))
     np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
+
+
+@pytest.mark.parametrize("backend,n,path", [
+    ("pallas", 2048, "pallas_interpret"),  # tiles: the kernel, interpreted off-TPU
+    ("pallas", 1000, "xla"),               # does not tile: the reference
+    ("xla", 2048, "xla"),
+])
+def test_record_paths_reports_each_dispatch(backend, n, path):
+    keys = jnp.asarray(np.sort(RNG.integers(0, 1 << 16, n)).astype(np.int32))
+    queries = jnp.asarray(RNG.integers(0, 1 << 16, 256).astype(np.int32))
+    ops.set_backend(backend)
+    try:
+        with ops.record_paths() as paths:
+            ops.lower_bound(keys, queries)
+            ops.upper_bound(keys, queries)
+    finally:
+        ops.set_backend("xla")
+    assert paths == [("lower_bound", path), ("upper_bound", path)]

@@ -72,12 +72,25 @@ def _cached_exec(backend: Backend, op: str, fn, *, donate_state: bool = False, s
     key = (backend, op, statics, ops.get_backend())
     f = _EXEC_CACHE.get(key)
     if f is None:
-        f = jax.jit(
-            functools.partial(fn, backend, *statics),
-            donate_argnums=(0,) if donate_state else (),
-        )
+        program = functools.partial(fn, backend, *statics)
+        # jit names the program after this: `jit__exec_<op>` in a device trace.
+        program.__name__, program.__qualname__ = fn.__name__, fn.__qualname__
+        f = jax.jit(program, donate_argnums=(0,) if donate_state else ())
         _EXEC_CACHE[key] = f
     return f
+
+
+def _span(method):
+    """Open the host span `dictionary.<method>` around a public method: it
+    records only while a profiler session is open."""
+    name = "dictionary." + method.__name__
+
+    @functools.wraps(method)
+    def traced(*args, **kwargs):
+        with jax.profiler.TraceAnnotation(name):
+            return method(*args, **kwargs)
+
+    return traced
 
 
 # -- op bodies (backend bound statically via the cache) -----------------------
@@ -435,6 +448,7 @@ class Dictionary:
 
     # -- updates -------------------------------------------------------------
 
+    @_span
     def update(self, keys, values=None, is_delete=None, valid=None) -> "Dictionary":
         """Mixed batch of any length: insert where ~is_delete, tombstone
         where is_delete; `valid=False` lanes are compacted away (they never
@@ -449,35 +463,36 @@ class Dictionary:
         every query). Returns the new handle (the old one's buffers are
         donated).
         """
-        caps = self._backend.caps
-        self._require("update", caps.supports_updates)
-        if self._validate:
-            _check_key_domain("update keys", keys, valid)
-        keys = _as_keys("keys", keys)
-        n = keys.shape[0]
-        if n == 0:
-            return self
+        with jax.profiler.TraceAnnotation("dictionary.update.prepare"):
+            caps = self._backend.caps
+            self._require("update", caps.supports_updates)
+            if self._validate:
+                _check_key_domain("update keys", keys, valid)
+            keys = _as_keys("keys", keys)
+            n = keys.shape[0]
+            if n == 0:
+                return self
 
-        if is_delete is None:
-            is_delete = jnp.zeros((n,), bool)
-        else:
-            is_delete = jnp.asarray(is_delete, bool)
-            if is_delete.ndim == 0:
-                is_delete = jnp.broadcast_to(is_delete, keys.shape)
-            if _is_concrete(is_delete) and bool(np.asarray(is_delete).any()):
-                self._require("delete", caps.supports_deletes)
-        if values is None:
-            values = jnp.zeros((n,), jnp.int32)
-        values = jnp.asarray(values, jnp.int32)
-        if values.ndim == 0:
-            values = jnp.broadcast_to(values, keys.shape)
-        if values.shape != keys.shape or is_delete.shape != keys.shape:
-            raise ValueError(
-                f"keys/values/is_delete shapes differ: {keys.shape}/"
-                f"{values.shape}/{is_delete.shape}"
-            )
-        if valid is not None:
-            valid = jnp.asarray(valid, bool)
+            if is_delete is None:
+                is_delete = jnp.zeros((n,), bool)
+            else:
+                is_delete = jnp.asarray(is_delete, bool)
+                if is_delete.ndim == 0:
+                    is_delete = jnp.broadcast_to(is_delete, keys.shape)
+                if _is_concrete(is_delete) and bool(np.asarray(is_delete).any()):
+                    self._require("delete", caps.supports_deletes)
+            if values is None:
+                values = jnp.zeros((n,), jnp.int32)
+            values = jnp.asarray(values, jnp.int32)
+            if values.ndim == 0:
+                values = jnp.broadcast_to(values, keys.shape)
+            if values.shape != keys.shape or is_delete.shape != keys.shape:
+                raise ValueError(
+                    f"keys/values/is_delete shapes differ: {keys.shape}/"
+                    f"{values.shape}/{is_delete.shape}"
+                )
+            if valid is not None:
+                valid = jnp.asarray(valid, bool)
 
         new_state = self._update_exec()(self._state, keys, values, is_delete, valid)
         return self._evolve(new_state)
@@ -498,6 +513,7 @@ class Dictionary:
         self._require("delete", self._backend.caps.supports_deletes)
         return self.update(keys, is_delete=True, valid=valid)
 
+    @_span
     def bulk_build(self, keys, values) -> "Dictionary":
         """Replace contents with n unique keys in one sort-and-segment pass
         (paper §5.2). n need not be a multiple of batch_size."""
@@ -513,6 +529,7 @@ class Dictionary:
         values = _as_keys("values", values, placement)
         return self._evolve(self._bulk_build_exec()(keys, values))
 
+    @_span
     def cleanup(self) -> "Dictionary":
         """Purge stale elements and tombstones (paper §3.6/§4.5).
 
@@ -522,6 +539,7 @@ class Dictionary:
         self._require("cleanup", self._backend.caps.supports_cleanup)
         return self._evolve(self._cleanup_exec()(self._state))
 
+    @_span
     def maintain(self, budget: Optional[int] = None) -> "Dictionary":
         """Budgeted incremental compaction: reclaim stale elements touching at
         most `budget` residents (STATIC Python int; each distinct budget
@@ -538,6 +556,7 @@ class Dictionary:
         self._require("maintain", self._backend.caps.supports_maintenance)
         return self._evolve(self._maintain_exec(budget)(self._state))
 
+    @_span
     def flush(self) -> "Dictionary":
         """Push staged (write-buffer) updates into the main structure.
 
@@ -571,7 +590,7 @@ class Dictionary:
         when nothing is staged or the backend has no buffer).
 
         For the LSM this is the cascade merge the carried batch triggers —
-        b * (trailing_ones(r) + 1) — so a scheduler can tell a cheap flush
+        b * 2^trailing_ones(r) elements — so a scheduler can tell a cheap flush
         (empty low levels) from one that will cascade deep, and time forced
         flushes accordingly. Sharded backends sum the shard-local costs."""
         f = _cached_exec(self._backend, "flush_cost", _exec_flush_cost)
@@ -579,6 +598,7 @@ class Dictionary:
 
     # -- queries -------------------------------------------------------------
 
+    @_span
     def lookup(self, keys) -> Tuple[jax.Array, jax.Array]:
         """Batched LOOKUP -> (found: bool[nq], values: int32[nq])."""
         if self._validate:
@@ -589,6 +609,7 @@ class Dictionary:
     def _resolved_plan(self, plan: Optional[QueryPlan]) -> QueryPlan:
         return (plan or QueryPlan()).resolved(self._backend.max_query_candidates)
 
+    @_span
     def count(self, k1, k2, plan: Optional[QueryPlan] = None):
         """COUNT(k1, k2) per query -> (counts: int32[nq], ok: bool[nq]).
 
@@ -602,6 +623,7 @@ class Dictionary:
         k1, k2 = _as_keys("k1", k1), _as_keys("k2", k2)
         return self._window_exec("count", plan)(self._state, k1, k2)
 
+    @_span
     def range(self, k1, k2, plan: Optional[QueryPlan] = None):
         """RANGE(k1, k2) -> (keys [nq, max_results], values, counts, ok).
 
@@ -622,6 +644,18 @@ class Dictionary:
     def overflowed(self):
         """bool scalar — did any update exceed the static capacity?"""
         return self._backend.overflowed(self._state)
+
+    def counters(self) -> Dict[str, int]:
+        """The work counters the state carries, read from its leaves with no
+        program: {"merged_elements": elements merged by pushes, flushes,
+        cleanups and maintains since the state was made}, summed over
+        shards. It wraps modulo b * 2^32 (shard counts summed unwrapped), so
+        take differences. {} for backends that keep no counter."""
+        merged = getattr(self._state, "merged", None)
+        if merged is None:
+            return {}
+        batches = int(np.asarray(merged).astype(np.uint32).sum(dtype=np.uint64))
+        return {"merged_elements": batches * self.batch_size}
 
 
 def _dict_flatten(d: Dictionary):

@@ -43,6 +43,16 @@ def _placebo(n):
     )
 
 
+def merged_batches(r):
+    """Batches one push into counter r merges: 2^t, t the trailing ones of r.
+
+    The carry joins the t full levels below the placement level, which hold
+    2^t - 1 batches; the result is the lowest zero bit of r (int32 scalar).
+    """
+    r = jnp.asarray(r, jnp.int32)
+    return (~r) & (r + 1)
+
+
 def placement_level(r):
     """Index of the lowest zero bit of r — the level a carry batch lands in.
 
@@ -50,9 +60,7 @@ def placement_level(r):
     block and sets the bit above it; that bit's level receives the merge of
     the carry with all the cleared (full) levels below.
     """
-    r = jnp.asarray(r, jnp.int32)
-    lowest_zero = (~r) & (r + 1)  # power of two
-    return jax.lax.population_count(lowest_zero - 1).astype(jnp.int32)
+    return jax.lax.population_count(merged_batches(r) - 1).astype(jnp.int32)
 
 
 def run_stale_count(run_kv):
@@ -73,6 +81,7 @@ def run_stale_count(run_kv):
     return real - jnp.sum(survivor_mask(run_kv)).astype(jnp.int32)
 
 
+@jax.named_scope("lsm.push")
 def push_batch(cfg, state, carry_kv, carry_val):
     """Push one pre-sorted b-wide batch through the binary-counter cascade.
 
@@ -87,6 +96,8 @@ def push_batch(cfg, state, carry_kv, carry_val):
     level j, which sizes exactly to b * 2^j. Levels above j pass through
     untouched (buffer donation forwards them), as do the write-buffer fields.
     On overflow (r == max_batches) the state is preserved and the latch set.
+    `merged` counts the 2^j batches merged (none on overflow). Its ops run
+    under the `lsm.push` name scope, which a device trace reports per op.
     """
     num_levels = cfg.num_levels
     would_overflow = state.r >= cfg.max_batches
@@ -141,6 +152,7 @@ def push_batch(cfg, state, carry_kv, carry_val):
         lvl_debt=new_debt,
         r=jnp.where(would_overflow, state.r, state.r + 1),
         overflowed=state.overflowed | would_overflow,
+        merged=state.merged + jnp.where(would_overflow, 0, merged_batches(state.r)),
     )
 
 

@@ -94,6 +94,7 @@ def lsm_cleanup(cfg: LSMConfig, state: LSMState) -> LSMState:
         r=r_new,
         overflowed=state.overflowed | overflow,
         lvl_debt=jnp.zeros((cfg.num_levels,), dtype=jnp.int32),
+        merged=state.merged + (1 << cfg.num_levels),  # every slot and the buffer
         **_fresh_buffer(b),
     )
 
@@ -140,6 +141,7 @@ def _compact_prefix(cfg: LSMConfig, state: LSMState, j: int) -> LSMState:
         lvl_debt=jnp.concatenate(
             [jnp.zeros((j + 1,), jnp.int32), state.lvl_debt[j + 1 :]]
         ),
+        merged=state.merged + (prefix_n // b),
     )
 
 

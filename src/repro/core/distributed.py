@@ -157,6 +157,7 @@ def dist_stage(cfg: DistLSMConfig, mesh, states, key_vars, values, count) -> LSM
     """
     state_spec = P(cfg.axis)
 
+    @jax.named_scope("lsm.stage")
     def body(states, key_vars, values, count):
         st = _local_state(states)
         shard = jax.lax.axis_index(cfg.axis).astype(jnp.int32)
@@ -454,6 +455,7 @@ def dist_bulk_build(cfg: DistLSMConfig, mesh, keys, values) -> LSMState:
             key_vars=kvs, values=vals, r=r_new,
             overflowed=jnp.zeros((), dtype=bool),
             lvl_debt=jnp.zeros((cfg.local.num_levels,), dtype=jnp.int32),
+            merged=jnp.zeros((), dtype=jnp.int32),
             **_fresh_buffer(b),
         )
         return _restack(st)

@@ -106,6 +106,11 @@ class LSMState(NamedTuple):
     # (cleanup.lsm_maintain). A scheduling signal only — queries never read
     # it, and results are exact at any debt level (docs/DESIGN.md §11).
     lvl_debt: jax.Array              # int32[num_levels]
+    # Elements merged, in batches of b: 2^t per push into a counter with t
+    # trailing ones (a flush is a push), 2^L per cleanup (every slot and the
+    # buffer), the prefix's batches per maintain. The LSM's write
+    # amplification; int32, wraps modulo 2^32, so readers take differences.
+    merged: jax.Array                # int32[]
 
 
 def level_view(cfg: LSMConfig, state: LSMState, i: int):
@@ -187,6 +192,7 @@ def lsm_init(cfg: LSMConfig) -> LSMState:
         r=jnp.zeros((), dtype=jnp.int32),
         overflowed=jnp.zeros((), dtype=bool),
         lvl_debt=jnp.zeros((cfg.num_levels,), dtype=jnp.int32),
+        merged=jnp.zeros((), dtype=jnp.int32),
         **_fresh_buffer(cfg.batch_size),
     )
 
@@ -222,6 +228,7 @@ def lsm_update(cfg: LSMConfig, state: LSMState, key_vars, values) -> LSMState:
     return _cascade(cfg, state, carry_kv, carry_val)
 
 
+@jax.named_scope("lsm.stage")
 def lsm_stage(cfg: LSMConfig, state: LSMState, key_vars, values, count) -> LSMState:
     """Stage one encoded sub-batch into the write buffer ("level −1").
 
@@ -234,7 +241,8 @@ def lsm_stage(cfg: LSMConfig, state: LSMState, key_vars, values, count) -> LSMSt
     Otherwise the *oldest* b pending elements flush through the cascade as
     one full batch (sorted newest-first within equal keys, so strict arrival
     order decides duplicates — docs/DESIGN.md §5) and the newest remainder
-    stays in the buffer. At most one cascade per call: count <= b.
+    stays in the buffer. At most one cascade per call: count <= b. Its ops
+    run under the `lsm.stage` name scope (a push inside it under `lsm.push`).
     """
     b = cfg.batch_size
     key_vars = jnp.asarray(key_vars, jnp.int32)
@@ -350,6 +358,7 @@ def lsm_bulk_build(cfg: LSMConfig, keys, values) -> LSMState:
         r=jnp.asarray(k, jnp.int32),
         overflowed=jnp.zeros((), dtype=bool),
         lvl_debt=jnp.zeros((cfg.num_levels,), dtype=jnp.int32),
+        merged=jnp.zeros((), dtype=jnp.int32),
         **_fresh_buffer(cfg.batch_size),
     )
 
@@ -369,16 +378,12 @@ def lsm_flush_cost(cfg: LSMConfig, state: LSMState):
     """Elements the cascade would touch if the buffer flushed *now* (int32
     scalar; 0 when the buffer is empty).
 
-    Pushing one batch into the binary counter merges through the trailing-one
-    levels of r (each full level is carried), so the merge reads and rewrites
-    b * (trailing_ones(r) + 1) arena elements. This is the cost the serving
-    scheduler weighs against buffer occupancy when deciding whether to flush
-    early or keep absorbing trickles (repro.serve.server admission policy).
+    Pushing one batch into the binary counter merges it with the t full
+    levels below the placement level (t = trailing_ones(r)), which hold
+    2^t - 1 batches, so the merge reads and rewrites b * 2^t elements: what
+    a push adds to `merged`. A scheduler can weigh it against buffer
+    occupancy when deciding whether to flush early or keep absorbing
+    trickles.
     """
-    trailing = jnp.zeros((), jnp.int32)
-    run = jnp.ones((), bool)
-    for lvl in range(cfg.num_levels):
-        run = run & (((state.r >> lvl) & 1) == 1)
-        trailing = trailing + run.astype(jnp.int32)
-    cost = cfg.batch_size * (trailing + 1)
+    cost = cfg.batch_size * cascade.merged_batches(state.r)
     return jnp.where(state.buf_n > 0, cost, 0).astype(jnp.int32)

@@ -112,7 +112,7 @@ def bitonic_sort_pairs(key_vars, values, *, interpret=False):
         in_specs=[block, block],
         out_specs=[block, block],
         out_shape=[shape, shape],
-        name="lsm_bitonic_sort",
+        name="bitonic_sort_pairs",
         interpret=interpret,
     )(
         key_vars.astype(jnp.int32).reshape(n // cols, cols),

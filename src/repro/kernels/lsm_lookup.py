@@ -107,7 +107,7 @@ def lower_bound_streamed(sorted_keys, query_keys, *, interpret=False):
         ],
         out_specs=pl.BlockSpec((QUERY_BLOCK, 1), lambda i, c: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((q, 1), jnp.int32),
-        name="lsm_lower_bound",
+        name="lower_bound_streamed",
         interpret=interpret,
     )(
         query_keys.astype(jnp.int32).reshape(q, 1),
@@ -226,7 +226,7 @@ def fused_lookup_runs(
             jax.ShapeDtypeStruct((q, 1), jnp.int32),
             jax.ShapeDtypeStruct((q, 1), jnp.int32),
         ],
-        name="lsm_fused_lookup",
+        name="fused_lookup_runs",
         interpret=interpret,
     )(query_keys.astype(jnp.int32).reshape(q, 1), flat)
     return best_kv[:, 0], best_val[:, 0]

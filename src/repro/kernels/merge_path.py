@@ -250,7 +250,7 @@ def merge_cascade_path(runs_kv, runs_val, *, compare_full=False, interpret=False
         ),
         out_shape=jax.ShapeDtypeStruct((2, total), jnp.int32),
         input_output_aliases={2 + len(operands): 0},
-        name="lsm_merge_cascade",
+        name="merge_cascade_path",
         interpret=interpret,
     )
 

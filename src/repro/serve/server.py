@@ -37,8 +37,10 @@ Architecture (modeled on sglang-jax's ModelRunner / forward-batch split):
 * **Admission/flush policy.** Update lanes stage into the facade's write
   buffer ("level −1"); the server tracks a host-side occupancy model of
   `pending()` (exact — it owns every mutation) and forces a `flush()` when
-  occupancy reaches ``flush_at_fraction * batch_size``, consulting
-  `flush_cost_estimate()` for reporting. A `maintenance_budget` piggybacks
+  occupancy reaches ``flush_at_fraction * batch_size``, whatever the flush
+  costs; `flush_cost_estimate()` (the b * 2^trailing_ones(r) elements that
+  flush would merge) is for callers that report it, such as
+  examples/dictionary_serving.py. A `maintenance_budget` piggybacks
   budgeted compaction on every update/flush step (debt-gated, see DESIGN.md
   §11), and `drain()` runs an explicit idle-time `maintain()` so churn debt
   is repaid outside the latency path.

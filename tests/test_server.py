@@ -268,7 +268,8 @@ class TestIntrospectionHooks:
         assert int(d.flush_cost_estimate()) == b    # r=2: no carry
         d = d.flush()                               # r=3
         d = d.insert(ks[30:40], np.ones(10, np.int32))
-        assert int(d.flush_cost_estimate()) == 3 * b  # carry through two levels
+        # r=3: the batch merges with levels 0 and 1 (b + 2b) into level 2
+        assert int(d.flush_cost_estimate()) == 4 * b
 
     def test_occupancy_sorted_array(self):
         d = Dictionary.create("sorted_array", capacity=256, batch_size=32)

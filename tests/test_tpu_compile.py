@@ -53,12 +53,18 @@ def _hlo(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _lowered(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).as_text()
+
+
 def _cascade(*xs):
     k = len(xs) // 2
     return merge_path.merge_cascade_path(list(xs[:k]), list(xs[k:]))
 
 
-# name -> (kernel, operand shapes): each at a shape of the main path.
+# name -> (kernel, operand shapes): each at a shape of the main path. The
+# kernel's `pallas_call` carries the same name, so a trace finds it; the
+# pairwise merge is the two-run case of the cascade kernel.
 KERNELS = {
     # count/range: 2^12 windows against the deepest level (2^27 slots)
     "lower_bound_streamed": (lsm_lookup.lower_bound_streamed, [(1 << 27,), (1 << 12,)]),
@@ -74,11 +80,15 @@ KERNELS = {
 }
 
 
+KERNEL_NAMES = {"merge_path": "merge_cascade_path"}
+
+
 @pytest.mark.parametrize("name", sorted(KERNELS))
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = KERNELS[name]
-    hlo = _hlo(fn, *(_spec(one_chip, *s) for s in shapes))
-    assert "tpu_custom_call" in hlo
+    args = [_spec(one_chip, *s) for s in shapes]
+    assert "tpu_custom_call" in _hlo(fn, *args)
+    assert f'kernel_name = "{KERNEL_NAMES.get(name, name)}"' in _lowered(fn, *args)
 
 
 def test_facade_lookup_compiles_with_pallas_kernel(one_chip, monkeypatch):

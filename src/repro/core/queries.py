@@ -6,7 +6,7 @@ sorted-array baseline passes its single run — the validation logic is shared.
 
 The count/range pipeline is the paper's five-stage bulk algorithm, adapted to
 fixed shapes (TPU-native: no dynamic allocation):
-  1. per-run lower/upper bound binary searches            (paper stage 1)
+  1. per-run lower/upper bound searches                   (paper stage 1)
   2. per-query candidate offsets via prefix sums          (paper stage 2)
   3. gather candidates into a [num_queries, max_candidates]
      padded tile, placebo-filled                          (paper stage 3)
@@ -48,7 +48,10 @@ def lookup_runs(runs, query_keys):
     On the Pallas backend the whole resolution collapses into one fused
     streaming kernel over the concatenated runs (`ops.lookup_runs_fused`);
     the per-run loop below is the XLA path and the semantic reference the
-    fused kernel is tested against (tests/test_fused_kernels.py).
+    fused kernel is tested against (tests/test_fused_kernels.py). Each run is
+    probed by `ops.lookup_level`: a run of whole 128-key rows and at least
+    2^14 slots by the fenced row-gather descent (kernels/search.py), a
+    smaller one by `jnp.searchsorted`.
     """
     query_keys = jnp.asarray(query_keys, jnp.int32)
     fused = ops.lookup_runs_fused(runs, query_keys)

@@ -1,9 +1,11 @@
 """jit'd dispatch wrappers for the LSM compute hot-spots.
 
 Backends:
-  "xla"    — the pure-jnp reference implementations (kernels/ref.py). This is
-             the default off-TPU: rank-based merge and `lax.sort` are already
-             near-roofline XLA programs on CPU, and identical semantics.
+  "xla"    — plain XLA programs, the default: merges and sorts are one
+             `lax.sort` (kernels/ref.py); the search of a run takes the fenced
+             row-gather descent (kernels/search.py) when the run's length
+             allows it (path "xla_fenced"), else `jnp.searchsorted`
+             (kernels/ref.py, path "xla").
   "pallas" — the Pallas TPU kernels (merge_path / bitonic_sort / lsm_lookup)
              with explicit BlockSpec VMEM tiling. On non-TPU platforms the
              kernels execute in interpret mode (used by the test suite to
@@ -22,7 +24,7 @@ import os
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref
+from repro.kernels import ref, search
 
 _BACKEND = os.environ.get("REPRO_KERNEL_BACKEND", "xla")
 # Open `record_paths()` logs; every dispatch appends (op, path) to each.
@@ -51,7 +53,7 @@ def _interpret() -> bool:
 @contextlib.contextmanager
 def record_paths():
     """Collect `(op, path)` for every dispatch traced inside the block, path
-    one of "pallas", "pallas_interpret" or "xla".
+    one of "pallas", "pallas_interpret", "xla_fenced" or "xla".
 
     Dispatch runs while a jitted program is traced, so a program that is
     already compiled records nothing: open the block around its first call.
@@ -64,11 +66,23 @@ def record_paths():
         _PATH_LOGS.remove(log)
 
 
-def _took(op: str, kernel: bool) -> bool:
-    path = ("pallas_interpret" if _interpret() else "pallas") if kernel else "xla"
+def _log(op: str, path: str) -> None:
     for log in _PATH_LOGS:
         log.append((op, path))
+
+
+def _took(op: str, kernel: bool) -> bool:
+    _log(op, ("pallas_interpret" if _interpret() else "pallas") if kernel else "xla")
     return kernel
+
+
+def _took_fenced(op: str, sorted_keys) -> bool:
+    """The XLA backend searches a run by the fenced descent when its length
+    allows (`search.viable`); `record_paths` names that path "xla_fenced"."""
+    fenced = _BACKEND == "xla" and search.viable(sorted_keys.shape[0])
+    if fenced:
+        _log(op, "xla_fenced")
+    return fenced
 
 
 def _pallas_viable_search(sorted_orig_keys, query_keys) -> bool:
@@ -144,6 +158,8 @@ def sort_pairs_recency(key_vars, values):
 
 def lower_bound(sorted_orig_keys, query_keys):
     """Vectorized lower-bound (first index with key >= query)."""
+    if _took_fenced("lower_bound", sorted_orig_keys):
+        return search.lower_bound_fenced(sorted_orig_keys, query_keys, "left")
     if _took("lower_bound", _pallas_viable_search(sorted_orig_keys, query_keys)):
         from repro.kernels import lsm_lookup
 
@@ -162,6 +178,8 @@ def upper_bound(sorted_orig_keys, query_keys):
     can store (user keys plus the placebo key, all < 2**30) compares <= such
     a query, so the answer is simply n.
     """
+    if _took_fenced("upper_bound", sorted_orig_keys):
+        return search.lower_bound_fenced(sorted_orig_keys, query_keys, "right")
     if _took("upper_bound", _pallas_viable_search(sorted_orig_keys, query_keys)):
         from repro.kernels import lsm_lookup
 
@@ -212,15 +230,31 @@ def lookup_runs_fused(runs, query_keys):
 
 
 def lookup_level(level_kv, level_val, query_keys):
-    """One-level lookup probe built on lower_bound (kernel-accelerated)."""
+    """One-level lookup probe: (hit, is_tomb, value) of the first slot whose
+    original key is >= each query.
+
+    A run the fenced descent accepts is searched by its key variables' rows
+    (`search.descend` with shift 1): the probed slot is read from the key row
+    the descent gathered and its value from one row gather of `level_val`,
+    so the level is never shifted or gathered slot by slot. Other runs take
+    `lower_bound` over the level's original keys.
+    """
     from repro.core import semantics as sem
 
-    orig = sem.original_key(level_kv)
-    idx = lower_bound(orig, query_keys)
-    idx_c = jnp.clip(idx, 0, level_kv.shape[0] - 1)
-    found_kv = level_kv[idx_c]
-    found_val = level_val[idx_c]
-    in_range = idx < level_kv.shape[0]
-    hit = in_range & (sem.original_key(found_kv) == query_keys)
-    is_tomb = sem.is_tombstone(found_kv)
-    return hit, is_tomb, found_val
+    query_keys = jnp.asarray(query_keys, jnp.int32)
+    n = level_kv.shape[0]
+    if _took_fenced("lower_bound", level_kv):
+        idx, r, kv_rows = search.descend(level_kv, query_keys, "left", shift=1)
+        val_rows = level_val.reshape(-1, search.LANES).at[r].get(mode="promise_in_bounds")
+        # The probed slot's lane, the last one when idx == n.
+        lane = jnp.minimum(idx - r * search.LANES, search.LANES - 1)
+        probed = jnp.arange(search.LANES, dtype=jnp.int32) == lane[:, None]
+        found_kv = jnp.sum(jnp.where(probed, kv_rows, 0), axis=1, dtype=jnp.int32)
+        found_val = jnp.sum(jnp.where(probed, val_rows, 0), axis=1, dtype=jnp.int32)
+    else:
+        idx = lower_bound(sem.original_key(level_kv), query_keys)
+        idx_c = jnp.clip(idx, 0, n - 1)
+        found_kv = level_kv[idx_c]
+        found_val = level_val[idx_c]
+    hit = (idx < n) & (sem.original_key(found_kv) == query_keys)
+    return hit, sem.is_tombstone(found_kv), found_val

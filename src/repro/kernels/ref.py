@@ -1,8 +1,9 @@
 """Pure-jnp oracles for the LSM kernels.
 
 These are the semantic ground truth for the Pallas kernels (merge_path,
-bitonic_sort, lsm_lookup) and also serve as the XLA fallback path used on
-platforms without Pallas support (the CPU, outside interpret-mode tests).
+bitonic_sort, lsm_lookup) and for the XLA path's fenced search
+(kernels/search.py). The merges and sorts are also the XLA path itself, as
+is `jnp.searchsorted` for runs the fenced search does not take (kernels/ops.py).
 Everything here is a sort or a binary search, with no data-dependent
 control flow.
 """
@@ -43,9 +44,9 @@ def merge_ref(a_kv, a_val, b_kv, b_val):
 def fused_lookup_ref(flat_kv, flat_val, query_keys):
     """Oracle for the fused multi-run lookup kernel: first flat match wins.
 
-    O(q * n) dense match matrix — test oracle only; the production XLA
-    fallback for lookups is the per-run loop in core/queries.py (per-run
-    searchsorted is O(q log n)).
+    O(q * n) dense match matrix — test oracle only; the XLA path for
+    lookups is the per-run loop in core/queries.py, each run probed by
+    `ops.lookup_level` (a fenced row-gather descent, O(q log n)).
     """
     flat_kv = jnp.asarray(flat_kv, jnp.int32)
     flat_val = jnp.asarray(flat_val, jnp.int32)

@@ -183,6 +183,7 @@ def test_lsm_update_with_pallas_backend_matches_xla():
     ("pallas", 2048, "pallas_interpret"),  # tiles: the kernel, interpreted off-TPU
     ("pallas", 1000, "xla"),               # does not tile: the reference
     ("xla", 2048, "xla"),
+    ("xla", 1 << 14, "xla_fenced"),        # whole rows, at the threshold: fenced
 ])
 def test_record_paths_reports_each_dispatch(backend, n, path):
     keys = jnp.asarray(np.sort(RNG.integers(0, 1 << 16, n)).astype(np.int32))

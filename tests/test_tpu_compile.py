@@ -107,3 +107,19 @@ def test_facade_lookup_compiles_with_pallas_kernel(one_chip, monkeypatch):
     )
     hlo = _hlo(lambda d, q: d.lookup(q), abstract, _spec(one_chip, B))
     assert "tpu_custom_call" in hlo
+
+
+def test_facade_lookup_on_xla_gathers_rows(one_chip, monkeypatch):
+    """The `lsm` facade's lookup at the benchmark's shapes (b = 2^16, L = 12)
+    on the XLA backend searches every run by row gathers of 128 keys and
+    runs no `while` loop: no run falls back to the scalar binary search."""
+    from repro.api import Dictionary
+
+    monkeypatch.setattr(ops, "_BACKEND", "xla")
+    d = Dictionary.create("lsm", batch_size=B, num_levels=12)
+    abstract = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), d
+    )
+    hlo = _hlo(lambda d, q: d.lookup(q), abstract, _spec(one_chip, B))
+    assert "slice_sizes={1,128}" in hlo
+    assert "while" not in hlo

@@ -3,7 +3,7 @@
 `lsm_update`, `lsm_stage`, `lsm_flush` (all pushing a carry batch through the
 binary counter), `lsm_bulk_build`, `lsm_cleanup`, and `lsm_maintain` (all
 re-slicing a sorted survivor prefix into levels) previously each carried their
-own copy of the merge/scatter/redistribute machinery. This module is the
+own copy of the merge/compact/redistribute machinery. This module is the
 single implementation:
 
   * `push_batch` — one binary-counter increment. Where the old `_cascade`
@@ -15,7 +15,8 @@ single implementation:
     fused K-way merge of [carry, level 0..j-1] (`ops.merge_cascade`). The
     executed program is O(b * 2^j) — the paper's amortized O(b log r) bound
     now holds per-branch, not just amortized over the cond chain.
-  * `compact_run` — survivor scatter into a placebo-prefilled buffer.
+  * `compact_run` — survivor compaction by one sort on the masked key: the
+    survivors in key order, then placebos.
   * `redistribute` — slice a sorted, unique-key prefix into levels by the
     bits of the new resident count (generalized to a level prefix, which is
     what budgeted maintenance compacts).
@@ -156,22 +157,31 @@ def push_batch(cfg, state, carry_kv, carry_val):
     )
 
 
+@jax.named_scope("lsm.compact")
 def compact_run(merged_kv, merged_val, keep, out_size: int):
-    """Scatter the keep-masked elements of a sorted run to the front of a
-    placebo-prefilled buffer of length `out_size` (order preserved — the
-    prefill IS the paper's "pad with placebo elements" step).
+    """Move the keep-masked elements of a merged run to the front, in key
+    order, and pad the rest with placebos (the paper's "pad with placebo
+    elements" step), giving arrays of length `out_size`.
+
+    Precondition: the kept elements are ascending in original key, with
+    unique original keys. Then one unstable 2-operand sort on the masked key
+    variable is a stable partition: every kept key variable is below
+    PLACEBO_KV, so the survivors land first in their own order, and every
+    other element becomes an identical (PLACEBO_KV, EMPTY_VALUE) pair.
 
     Returns (kv, val, total) where total is the UNCLAMPED survivor count;
-    survivors past out_size (and non-survivors) scatter out of range and are
-    dropped, so the caller decides whether an overflow latches.
+    survivors past out_size (the largest keys) are dropped, so the caller
+    decides whether an overflow latches.
     """
     total = jnp.sum(keep).astype(jnp.int32)
-    tgt = jnp.cumsum(keep) - 1
-    tgt = jnp.where(keep & (tgt < out_size), tgt, out_size)
-    kv, val = _placebo(out_size)
-    kv = kv.at[tgt].set(merged_kv, mode="drop")
-    val = val.at[tgt].set(merged_val, mode="drop")
-    return kv, val, total
+    kv = jnp.where(keep, merged_kv, sem.PLACEBO_KV)
+    val = jnp.where(keep, merged_val, sem.EMPTY_VALUE)
+    kv, val = jax.lax.sort((kv, val), dimension=0, is_stable=False, num_keys=1)
+    pad = out_size - kv.shape[0]
+    if pad > 0:
+        pk, pv = _placebo(pad)
+        return jnp.concatenate([kv, pk]), jnp.concatenate([val, pv]), total
+    return kv[:out_size], val[:out_size], total
 
 
 def redistribute(cfg, compact_kv, compact_val, r_new, hi_level: int | None = None):

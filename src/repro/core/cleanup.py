@@ -11,8 +11,9 @@ bounded slices). Both operations here are built on the shared cascade engine
       1. ONE fused K-way merge of the write buffer (newest) and every level
          (`ops.merge_cascade` — previously a pairwise chain);
       2. survivor mask: first of each equal-key segment, regular, not placebo;
-      3. compact survivors into a placebo-prefilled arena (`compact_run` —
-         the prefill IS the paper's "pad with < b placebos" step);
+      3. compact the survivors by one sort on the masked key (`compact_run`:
+         non-survivors become placebos and sort last, which IS the paper's
+         "pad with < b placebos" step);
       4. re-slice by the bits of the new resident count (`redistribute`).
     Folding the buffer into the merge empties it without burning a batch
     slot; because the buffer adds up to b elements beyond the level arenas,
@@ -81,6 +82,7 @@ def lsm_cleanup(cfg: LSMConfig, state: LSMState) -> LSMState:
     ]
     merged_kv, merged_val = ops.merge_cascade(runs)
     survives = survivor_mask(merged_kv)
+    # Unique, ascending survivors: the first element of each equal-key segment.
     compact_kv, compact_val, total = cascade.compact_run(
         merged_kv, merged_val, survives, cfg.capacity
     )
@@ -125,6 +127,7 @@ def _compact_prefix(cfg: LSMConfig, state: LSMState, j: int) -> LSMState:
     keep = jnp.where(
         covers_all, newest_per_key & ~sem.is_tombstone(merged_kv), newest_per_key
     )
+    # Unique, ascending survivors: `keep` implies the newest element per key.
     compact_kv, compact_val, total = cascade.compact_run(
         merged_kv, merged_val, keep, prefix_n
     )

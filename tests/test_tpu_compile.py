@@ -1,4 +1,5 @@
-"""Compile the Pallas kernels and the facade's lookup for a described TPU v5e.
+"""Compile the Pallas kernels, the facade's lookup and the cleanup for a
+described TPU v5e.
 
 No chip is needed: the TPU compiler is installed and compiles for a topology
 that is described, not attached. This catches what interpret mode cannot —
@@ -11,6 +12,7 @@ may load the TPU library, and every test worker imports this file.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -123,3 +125,24 @@ def test_facade_lookup_on_xla_gathers_rows(one_chip, monkeypatch):
     hlo = _hlo(lambda d, q: d.lookup(q), abstract, _spec(one_chip, B))
     assert "slice_sizes={1,128}" in hlo
     assert "while" not in hlo
+
+
+def test_cleanup_compacts_without_scatter(one_chip):
+    """`lsm_cleanup` at the benchmark's shapes (b = 2^16, L = 12) holds two
+    sorts of all 2^28 slots, the 4-operand merge and the 2-operand
+    compaction, and no scatter. At this size the TPU compiler lowers a
+    scatter as a hidden sort of its indices plus a scatter fusion, which a
+    compile at 2^20 slots does not show."""
+    from repro.core import LSMConfig, lsm_cleanup, lsm_init
+
+    cfg = LSMConfig(batch_size=B, num_levels=12)
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: lsm_init(cfg)),
+    )
+    hlo = _hlo(lambda s: lsm_cleanup(cfg, s), state)
+    # A scatter op, or the index sort and kCustom fusion it became: those
+    # carry the scatter's op name.
+    assert not re.search(r"\sscatter\(|/scatter\"", hlo)
+    sorts = re.findall(r"= \((.*?)\) sort\(", hlo)
+    assert sorted(shapes.count("s32[") for shapes in sorts) == [2, 4]

@@ -11,7 +11,7 @@ exit code is 1 if any row regresses (the CI perf lane fails on it). New rows
 grow across PRs.
 
 The threshold is deliberately generous (default 3.0x): shared-CI wall-clock
-noise on CPU interpret/XLA paths is large, and this lane exists to catch
+noise on the CPU is large, and this lane exists to catch
 order-of-magnitude regressions (an accidentally quadratic path, a lost jit
 cache), not single-digit percent drift. Tighten it when runners are
 dedicated.

@@ -29,7 +29,6 @@ def main() -> None:
         cleanup_bench,
         common,
         fig2_effective_rate,
-        kernel_bench,
         serve_bench,
         sharded_bench,
         streaming_bench,
@@ -50,7 +49,6 @@ def main() -> None:
                                                 num_batches=16 if args.quick else 48),
         "cleanup": lambda: cleanup_bench.run(log_n=14 if args.quick else 18,
                                              log_b=11 if args.quick else 14),
-        "kernels": lambda: kernel_bench.run(log_n=16 if args.quick else 20),
         "sharded": lambda: sharded_bench.run(log_b=10 if args.quick else 11,
                                              num_batches=8 if args.quick else 16,
                                              nq=512 if args.quick else 2048),

@@ -45,14 +45,6 @@ def run(log_n: int = 18, log_bs=(14, 16), r_samples: int = 6) -> None:
             rs = rates[kind]
             emit(f"table3/lookup_{kind}_b2^{log_b}", 1.0 / (hmean(rs) * 1e6) if rs else 0,
                  f"mean={hmean(rs):.1f}Mq/s min={min(rs):.1f} max={max(rs):.1f}")
-        # Fused read path (kernels/lsm_lookup.fused_lookup_runs): on the
-        # Pallas backend ONE streaming launch replaces the per-run resolution
-        # loop (one lower_bound launch per run + gather/validate). XLA wall
-        # time above is unchanged by design — the win is launch count and
-        # HBM re-reads on TPU; record the static reduction here.
-        num_runs = len(d.state.key_vars) + 1  # levels + write buffer
-        emit(f"table3/fused_launch_reduction_b2^{log_b}", 0.0,
-             f"runs_probed={num_runs}->1 launch (pallas path)")
 
     # SA baseline
     sa = Dictionary.create("sorted_array", capacity=n, validate=False)

@@ -15,10 +15,6 @@ state), in one process, through the public entry points:
            and range at the paper's expected range lengths 8 and 1024
   server   DictionaryServer over the same dictionary: tenants above the bulk
            keys replay a "mixed" trace
-  pallas   count, range, staged updates, cleanup, then one lookup, on the
-           Pallas kernels: every program must hold Mosaic kernels
-           (`tpu_custom_call`) and no op may take the XLA path; plus the
-           bitonic sort kernel on one batch
 
 Four chips run only the sharded load, update and query sequence, and check
 that every state leaf spans the four devices and that no device holds the
@@ -29,10 +25,9 @@ unique keys plus searchsorted). Each phase prints its wall and compile
 seconds, the seconds of this script's own NumPy work (making data, keeping
 the oracle), the device's peak bytes and the kernel path each op took. Every
 program a run needs compiles once, concurrently, through
-`Dictionary.precompile`: the XLA ones before the bulk build, the Pallas ones
-at the start of their phase. The last line is a JSON object naming the
-device; the script exits non-zero, without that line, when no TPU is present
-or any check fails.
+`Dictionary.precompile` before the bulk build. The last line is a JSON
+object naming the device; the script exits non-zero, without that line, when
+no TPU is present or any check fails.
 """
 
 from __future__ import annotations
@@ -51,7 +46,6 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "src"))
 
 import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 from jax import monitoring  # noqa: E402
 
 from repro.api import Dictionary, QueryPlan  # noqa: E402
@@ -221,21 +215,21 @@ def plan_for(length: int) -> QueryPlan:
     return QueryPlan(max_candidates=max(32, 2 * length), max_results=max(32, 2 * length))
 
 
-def check_count_range(d: Dictionary, oracle: Oracle, scale: Scale, rng, what: str) -> None:
+def check_count_range(d: Dictionary, oracle: Oracle, scale: Scale, rng) -> None:
     for length in scale.range_lengths:
         k1, k2 = window_queries(scale, rng, length)
         plan = plan_for(length)
         counts, ok = d.count(k1, k2, plan)
         _, ecnt = oracle.window(k1, k2)
-        check(np.asarray(ok).all(), f"{what}: count L={length} truncated")
-        check(np.array_equal(np.asarray(counts), ecnt), f"{what}: count L={length} differs")
+        check(np.asarray(ok).all(), f"count L={length} truncated")
+        check(np.array_equal(np.asarray(counts), ecnt), f"count L={length} differs")
         keys, vals, rcnt, rok = d.range(k1, k2, plan)
         ekeys, evals, _ = oracle.range(k1, k2, plan.max_results)
-        check(np.asarray(rok).all(), f"{what}: range L={length} truncated")
-        check(np.array_equal(np.asarray(rcnt), ecnt), f"{what}: range L={length} counts differ")
-        check(np.array_equal(np.asarray(keys), ekeys), f"{what}: range L={length} keys differ")
-        check(np.array_equal(np.asarray(vals), evals), f"{what}: range L={length} values differ")
-        print(f"  {what}: L={length} mean count {ecnt.mean():.3f}, max {ecnt.max()}", flush=True)
+        check(np.asarray(rok).all(), f"range L={length} truncated")
+        check(np.array_equal(np.asarray(rcnt), ecnt), f"range L={length} counts differ")
+        check(np.array_equal(np.asarray(keys), ekeys), f"range L={length} keys differ")
+        check(np.array_equal(np.asarray(vals), evals), f"range L={length} values differ")
+        print(f"  L={length} mean count {ecnt.mean():.3f}, max {ecnt.max()}", flush=True)
 
 
 # -- phase bookkeeping -------------------------------------------------------------
@@ -353,11 +347,6 @@ def precompile(d: Dictionary, scale: Scale, **extra) -> dict:
                         cleanup=True, **extra)
 
 
-def holds_kernel(compiled) -> bool:
-    """Does a compiled program call a Mosaic TPU kernel?"""
-    return "tpu_custom_call" in compiled.as_text()
-
-
 def load(scale: Scale, seed: int, phase, **options):
     """Make the data, compile every XLA program of the run, bulk-build."""
     rng = np.random.default_rng(seed)
@@ -390,7 +379,7 @@ def run_updates_and_queries(d, oracle, scale, rng, phase):
     print(f"resident elements after cleanup: {resident(d)} (oracle {len(oracle.keys)})", flush=True)
     with phase("queries"):
         check_lookup(d, oracle, q, "lookup")
-        check_count_range(d, oracle, scale, rng, "xla")
+        check_count_range(d, oracle, scale, rng)
     return d
 
 
@@ -462,52 +451,10 @@ def check_trace(trace, results) -> None:
             check(vals[0, :n].tolist() == [live[k] for k in inside], f"server op {i}: range values")
 
 
-def run_pallas(d, oracle, scale, rng, phase):
-    """The same ops on the Pallas kernels. The fused lookup compares every
-    query with every resident slot, so it runs once, last: after the staged
-    updates and the cleanup, on keys those updates wrote and deleted."""
-    ops.set_backend("pallas")
-    try:
-        with phase("pallas_compile"):
-            compiled = precompile(d, scale)
-            sort = jax.jit(ops.sort_pairs)
-            lanes = jnp.zeros((scale.b,), jnp.int32)
-            compiled[("sort", scale.b)] = sort.lower(lanes, lanes).compile()
-            for name, c in compiled.items():
-                check(holds_kernel(c), f"the {name} program calls no Pallas kernel")
-        paths = phase.rows[-1]["paths"]
-        check(paths and not any(p.endswith(":xla") for p in paths),
-              f"an op took the XLA path on the Pallas backend: {paths}")
-        with phase("pallas_count_range"):
-            check_count_range(d, oracle, scale, rng, "pallas")
-        with phase("pallas_sort"):
-            check_sort(sort, scale.b, rng)
-        with phase("pallas_update"):
-            d, q = apply_calls(d, oracle, scale, update_batches(scale, oracle, rng), rng)
-            jax.block_until_ready(d.state)
-        with phase("pallas_cleanup_lookup"):
-            d = d.cleanup()
-            check_lookup(d, oracle, q, "pallas lookup after staged updates and cleanup")
-    finally:
-        ops.set_backend("xla")
-    return d
-
-
-def check_sort(sort, n: int, rng) -> None:
-    """The bitonic tile sort plus its Merge Path rounds, on one batch."""
-    kv = rng.integers(0, np.iinfo(np.int32).max, n, dtype=np.int32)
-    val = np.arange(n, dtype=np.int32)
-    out_kv, out_val = (np.asarray(x) for x in sort(jnp.asarray(kv), jnp.asarray(val)))
-    check(np.array_equal(out_kv, np.sort(kv)), "bitonic sort: keys out of order")
-    check(np.array_equal(kv[out_val], out_kv), "bitonic sort: values detached from keys")
-
-
-def run_one_chip(scale: Scale, seed: int, phase: Phases, pallas: bool = True) -> None:
+def run_one_chip(scale: Scale, seed: int, phase: Phases) -> None:
     d, oracle, rng = load(scale, seed, phase, backend="lsm")
     d = run_updates_and_queries(d, oracle, scale, rng, phase)
-    d = run_server(d, oracle, scale, seed, phase)
-    if pallas:
-        run_pallas(d, oracle, scale, rng, phase)
+    run_server(d, oracle, scale, seed, phase)
 
 
 # -- the four-chip run -------------------------------------------------------------

@@ -36,7 +36,7 @@ from repro.api.backend import (  # noqa: F401
     KeyDomainError,
     OccupancyStats,
     available_backends,
-    get_backend_class,
+    backend_class,
     register_backend,
 )
 from repro.api.plan import QueryPlan  # noqa: F401

@@ -253,7 +253,7 @@ def register_backend(cls: Type[Backend]) -> Type[Backend]:
     return cls
 
 
-def get_backend_class(name: str) -> Type[Backend]:
+def backend_class(name: str) -> Type[Backend]:
     try:
         return _REGISTRY[name]
     except KeyError:

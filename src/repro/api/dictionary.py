@@ -49,18 +49,15 @@ from repro.api.backend import (
     Backend,
     CapabilityError,
     KeyDomainError,
-    get_backend_class,
+    backend_class,
 )
 from repro.api.plan import QueryPlan
 from repro.core import semantics as sem
 from repro.core.lsm import compact_real
-from repro.kernels import ops
 
-# (backend, op, statics, kernel backend) -> jitted executable. jax.jit keeps
-# the per-shape specialization under each entry, so this stays small: one
-# entry per (config, op) the process touches. The kernel backend is part of
-# the key because it is read while tracing: after `ops.set_backend(...)` an
-# op must trace anew, not reuse a program built on the other kernels.
+# (backend, op, statics) -> jitted executable. jax.jit keeps the per-shape
+# specialization under each entry, so this stays small: one entry per
+# (config, op) the process touches.
 _EXEC_CACHE: Dict[tuple, object] = {}
 
 # `precompile` threads: XLA compiles each program on one core, so the
@@ -69,7 +66,7 @@ _COMPILE_THREADS = 16
 
 
 def _cached_exec(backend: Backend, op: str, fn, *, donate_state: bool = False, statics=()):
-    key = (backend, op, statics, ops.get_backend())
+    key = (backend, op, statics)
     f = _EXEC_CACHE.get(key)
     if f is None:
         program = functools.partial(fn, backend, *statics)
@@ -295,7 +292,7 @@ class Dictionary:
         stop-the-world `cleanup()` latency spike. `maintain()` can also be
         called explicitly at any time.
         """
-        be = get_backend_class(backend).from_options(**options)
+        be = backend_class(backend).from_options(**options)
         if flush_threshold is not None:
             t = int(flush_threshold)
             if not 1 <= t <= be.batch_size:
@@ -416,7 +413,7 @@ class Dictionary:
         later `maintain` calls. `flush`, `cleanup`: those programs too.
 
         Each is the very program the method runs (same statics, donation,
-        kernel backend, argument shapes and placement); jax keys compiled
+        argument shapes and placement); jax keys compiled
         programs on exactly that, so the later call finds it. Returns
         {(op, *shape or static): compiled program}.
         """

@@ -45,18 +45,11 @@ INT32_MAX = jnp.iinfo(jnp.int32).max
 def lookup_runs(runs, query_keys):
     """LOOKUP(k) over newest-first runs: first matching run wins; tombstone → ⊥.
 
-    On the Pallas backend the whole resolution collapses into one fused
-    streaming kernel over the concatenated runs (`ops.lookup_runs_fused`);
-    the per-run loop below is the XLA path and the semantic reference the
-    fused kernel is tested against (tests/test_fused_kernels.py). Each run is
-    probed by `ops.lookup_level`: a run of whole 128-key rows and at least
-    2^14 slots by the fenced row-gather descent (kernels/search.py), a
-    smaller one by `jnp.searchsorted`.
+    Each run is probed by `ops.lookup_level`: a run of whole 128-key rows and
+    at least 2^14 slots by the fenced row-gather descent (kernels/search.py),
+    any other by `jnp.searchsorted`.
     """
     query_keys = jnp.asarray(query_keys, jnp.int32)
-    fused = ops.lookup_runs_fused(runs, query_keys)
-    if fused is not None:
-        return fused
     nq = query_keys.shape[0]
     resolved = jnp.zeros((nq,), dtype=bool)
     found = jnp.zeros((nq,), dtype=bool)

@@ -1,1 +1,1 @@
-"""Pallas TPU kernels for the LSM hot-spots (+ pure-jnp oracles in ref.py)."""
+"""The LSM's merges, sorts and searches: one XLA path per op (ops.py)."""

@@ -1,11 +1,8 @@
-"""Pure-jnp oracles for the LSM kernels.
-
-These are the semantic ground truth for the Pallas kernels (merge_path,
-bitonic_sort, lsm_lookup) and for the XLA path's fenced search
-(kernels/search.py). The merges and sorts are also the XLA path itself, as
-is `jnp.searchsorted` for runs the fenced search does not take (kernels/ops.py).
-Everything here is a sort or a binary search, with no data-dependent
-control flow.
+"""Pure-jnp merges, sorts and searches: the XLA path's merges and sorts,
+and `jnp.searchsorted` for runs the fenced search does not take
+(kernels/ops.py); also the reference the fenced search (kernels/search.py)
+is tested against. Everything here is a sort or a binary search, with no
+data-dependent control flow.
 """
 
 from __future__ import annotations
@@ -25,8 +22,7 @@ def merge_cascade_ref(runs_kv, runs_val):
     appear first in the merged result"); within a run, input order holds.
     That is exactly a stable sort of the runs concatenated newest first, so
     this is one `lax.sort` — no gathers or scatters, which the TPU executes
-    slowly at the paper's sizes. It is the semantic oracle for
-    `merge_path.merge_cascade_path`.
+    slowly at the paper's sizes.
     """
     kv = jnp.concatenate([jnp.asarray(x, jnp.int32) for x in runs_kv])
     val = jnp.concatenate([jnp.asarray(x, jnp.int32) for x in runs_val])
@@ -34,29 +30,6 @@ def merge_cascade_ref(runs_kv, runs_val):
         (sem.original_key(kv), kv, val), dimension=0, is_stable=True, num_keys=1
     )
     return kv, val
-
-
-def merge_ref(a_kv, a_val, b_kv, b_val):
-    """Stable merge of two sorted runs; `a` is the NEWER run."""
-    return merge_cascade_ref([a_kv, b_kv], [a_val, b_val])
-
-
-def fused_lookup_ref(flat_kv, flat_val, query_keys):
-    """Oracle for the fused multi-run lookup kernel: first flat match wins.
-
-    O(q * n) dense match matrix — test oracle only; the XLA path for
-    lookups is the per-run loop in core/queries.py, each run probed by
-    `ops.lookup_level` (a fenced row-gather descent, O(q log n)).
-    """
-    flat_kv = jnp.asarray(flat_kv, jnp.int32)
-    flat_val = jnp.asarray(flat_val, jnp.int32)
-    query_keys = jnp.asarray(query_keys, jnp.int32)
-    match = sem.original_key(flat_kv)[None, :] == query_keys[:, None]
-    any_match = match.any(axis=1)
-    first = jnp.argmax(match, axis=1)
-    best_kv = jnp.where(any_match, flat_kv[first], sem.PLACEBO_KV)
-    best_val = jnp.where(any_match, flat_val[first], sem.EMPTY_VALUE)
-    return best_kv, best_val
 
 
 def sort_ref(key_vars, values):

@@ -202,9 +202,9 @@ class TestCapabilities:
                 op()
 
     def test_lsm_sharded_capability_row_is_full(self):
-        from repro.api import get_backend_class
+        from repro.api import backend_class
 
-        caps = get_backend_class("lsm_sharded").caps
+        caps = backend_class("lsm_sharded").caps
         assert caps.supports_updates and caps.supports_deletes
         assert caps.supports_ordered_queries and caps.supports_cleanup
         assert caps.supports_bulk_build
